@@ -31,8 +31,11 @@ _DTYPE_BYTES = {
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _COMP_HDR_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w\.\-]+)\s*\(.*->.*\{\s*$")
+# a tuple result type may nest one level of parens: TPU layouts spell
+# tiles as ``{1,0:T(8,128)(2,1)}``
 _INSTR_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%([\w\.\-]+)\s*=\s*(\([^)]*\)|\w+\[[\d,]*\]\S*)\s+"
+    r"^\s*(?:ROOT\s+)?%([\w\.\-]+)\s*=\s*"
+    r"(\((?:[^()]|\([^()]*\))*\)|\w+\[[\d,]*\]\S*)\s+"
     r"([\w\-]+)\((.*)$")
 _OPERAND_RE = re.compile(r"%([\w\.\-]+)")
 _CALL_ONE_RE = re.compile(
@@ -426,12 +429,10 @@ def analyze(text: str, top_n: int = 0, pod_size: int = 256,
 
 
 def flat_cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as one flat dict, across JAX versions.
+    """``Compiled.cost_analysis()`` as a plain dict.
 
     These are the trip-count-UNAWARE numbers (each while body counted
     once) that ``analyze`` corrects; they're retained in dry-run records
-    for reference.  Legacy JAX returns a list of per-program dicts, new
-    JAX a dict — normalization lives in parallel/compat.py.
+    for reference.
     """
-    from repro.parallel.compat import cost_analysis_dict
-    return cost_analysis_dict(compiled)
+    return dict(compiled.cost_analysis())

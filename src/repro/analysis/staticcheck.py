@@ -21,7 +21,7 @@ detector in isolation so one seeded defect yields exactly one violation):
 
   * **jaxpr audit** (``audit_jaxpr`` / ``audit_cells(level='jaxpr')``):
     traces ``make_pipelined_loss`` grads through ``compat.abstract_mesh``
-    — device-free, works on BOTH shard_map lowerings — and walks every
+    — device-free — and walks every
     (sub-)jaxpr for ppermute bijectivity/schedule, payload/index dtype
     contract, and pod-axis collective leaks.
   * **HLO audit** (``audit_hlo_text`` / ``audit_cells(level='hlo')``):
@@ -35,7 +35,7 @@ detector in isolation so one seeded defect yields exactly one violation):
     ``_tick_loop``-reachable code, nested ``jax.jit``, ``pallas_call``
     without the ``interpret`` plumbing idiom.
 
-CLI (the CI ``staticcheck`` job runs this on both JAX legs)::
+CLI (the CI ``staticcheck`` job runs this)::
 
     python -m repro.analysis.staticcheck                 # jaxpr + lint + model
     python -m repro.analysis.staticcheck --level full    # + compiled-HLO audit
@@ -385,7 +385,7 @@ def audit_record_honesty(record: dict, *, rtol: float = 1e-6, **knobs):
 
 
 # ---------------------------------------------------------------------------
-# jaxpr-level audit (device-free; both lowerings via abstract mesh).
+# jaxpr-level audit (device-free, via an abstract mesh).
 # ---------------------------------------------------------------------------
 
 # pod-axis collectives that are NOT the pipeline hop: any of these inside
@@ -732,7 +732,7 @@ def audit_hlo_text(text: str, *, pod_size: int, num_stages: int,
 
 
 # ---------------------------------------------------------------------------
-# Fixture cells: both lowerings x the re-planner's reachable cell set.
+# Fixture cells: the re-planner's reachable cell set.
 # ---------------------------------------------------------------------------
 
 # the fixture cell (mirrors the tier-1 tiny config; float32 so the
@@ -814,16 +814,14 @@ def audit_cells(level: str = "jaxpr", wires=None, vs=None,
     ``cells`` pins an explicit ``[(wire, v), ...]`` list and wins over
     both.  ``level``:
 
-      * ``'jaxpr'`` — abstract-mesh tracing, zero devices needed (works
-        on both JAX generations; audits whichever shard_map lowering
-        ``compat.CAPS`` selects on this interpreter);
+      * ``'jaxpr'`` — abstract-mesh tracing, zero devices needed;
       * ``'hlo'`` — compiles each cell (requires
         ``mesh_shape`` devices, e.g. XLA_FLAGS
         --xla_force_host_platform_device_count=8) and audits the
         optimized module text, including byte honesty.
 
     Returns ``(violations, cells)`` where ``cells`` is a list of per-cell
-    stat dicts keyed leg-independently (``wire/v``).
+    stat dicts keyed by ``wire/v``.
     """
     import jax
 
@@ -836,8 +834,6 @@ def audit_cells(level: str = "jaxpr", wires=None, vs=None,
         cells = list(cells)
     violations = []
     out_cells = []
-    lowering = "partial-manual" if compat.CAPS.partial_manual \
-        else "full-manual"
     for wire, v in cells:
         key = f"{wire}/v{v}"
         if level == "jaxpr":
@@ -874,7 +870,6 @@ def audit_cells(level: str = "jaxpr", wires=None, vs=None,
                for x in vio]
         violations += vio
         out_cells.append({"cell": key, "level": level,
-                          "lowering": lowering,
                           "violations": len(vio), "stats": stats})
     # the custom_vjp residual contract is cell-independent — audit once
     # per coded grammar
@@ -883,7 +878,6 @@ def audit_cells(level: str = "jaxpr", wires=None, vs=None,
             vio = audit_wire_custom_vjp(wire)
             violations += vio
             out_cells.append({"cell": f"vjp:{wire}", "level": "jaxpr",
-                              "lowering": lowering,
                               "violations": len(vio), "stats": {}})
     return violations, out_cells
 
@@ -900,9 +894,9 @@ ROOFLINE_FIXTURE = os.path.join(
 def build_report(level: str = "jaxpr", lint_paths=None,
                  record_path: str | None = None) -> dict:
     """Run every layer the ``level`` admits and assemble the JSON
-    violation report the CI job uploads.  Leg-independent fields only in
-    the diffable core (``ok``/``by_class``/``cells`` keys): lowering and
-    eqn counts live in per-cell stats, which ``diff_report`` ignores."""
+    violation report the CI job uploads.  The diffable core is the
+    ``ok``/``by_class``/``cells`` keys: eqn counts live in per-cell
+    stats, which ``diff_report`` ignores."""
     from repro.analysis import lint as lint_pack
 
     violations = []

@@ -1,8 +1,8 @@
 """Architecture registry: ``--arch <id>`` resolves through here."""
 from __future__ import annotations
 
-from repro.configs.base import (SHAPES, ArchSpec, ShapeSpec, cache_specs,
-                                input_specs, param_specs)
+from repro.configs.base import (SHAPES, SIZES, ArchSpec, ShapeSpec,
+                                cache_specs, input_specs, param_specs)
 from repro.configs import (codeqwen15_7b, command_r_plus_104b, granite_moe_3b,
                            paligemma_3b, qwen15_4b, qwen3_moe_30b,
                            recurrentgemma_2b, resnet18_cifar10, rwkv6_3b,

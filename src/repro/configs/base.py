@@ -3,6 +3,9 @@
 Every assigned architecture contributes an ``ArchSpec`` with
   * ``full``   — the exact published config (dry-run / roofline only),
   * ``smoke``  — a reduced same-family config (CPU tests),
+  * ``chip``   — optional: the published widths cut in depth and in the
+                 slices held (vocabulary, experts) to one chip's share of
+                 a stated deployment; the config's module lists the cuts,
   * ``shapes`` — which of the assigned input shapes apply (with skip reasons).
 
 ``input_specs`` builds ``jax.ShapeDtypeStruct`` stand-ins for every model
@@ -37,6 +40,10 @@ SHAPES = {
 }
 
 
+#: ``--size`` values the launchers accept.
+SIZES = ("smoke", "chip", "full")
+
+
 @dataclasses.dataclass(frozen=True)
 class ArchSpec:
     name: str
@@ -44,6 +51,19 @@ class ArchSpec:
     smoke: LMConfig
     # shape name -> None (runs) | str (skip reason)
     skips: dict
+    chip: LMConfig | None = None
+
+    def config(self, size: str) -> LMConfig:
+        """The config for ``--size``; an architecture without a chip
+        share raises instead of falling back to another size."""
+        if size not in SIZES:
+            raise ValueError(f"size {size!r} not in {SIZES}")
+        cfg = getattr(self, size)
+        if cfg is None:
+            raise ValueError(
+                f"{self.name} has no chip-share config (configs/*.py "
+                "sets ArchSpec.chip) — use --size smoke or full")
+        return cfg
 
     def applicable(self, shape: str) -> bool:
         return self.skips.get(shape) is None

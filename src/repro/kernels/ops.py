@@ -1,9 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) every kernel runs in ``interpret=True`` mode —
-the kernel body executes as traced JAX ops, which is what the tests
-validate against the ``ref.py`` oracles.  On a real TPU backend the same
-calls compile to Mosaic.
+On a TPU backend the kernels compile to Mosaic.  On the CPU backend (the
+test suite) they run in ``interpret=True`` mode — the kernel body
+executes as traced JAX ops, which is what the tests validate against the
+``ref.py`` oracles.  Any other backend is an error, not a silent
+interpreter.
 """
 from __future__ import annotations
 
@@ -19,7 +20,12 @@ from repro.kernels import wire_codec as _wc
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels compile for 'tpu' and interpret on 'cpu'; "
+            f"backend {backend!r} has neither path")
+    return backend == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window"))

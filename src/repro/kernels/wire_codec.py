@@ -58,12 +58,19 @@ def wire_block(dim: int, block: int = 256) -> int:
 
 
 def _row_tile(rows: int, cap: int = 128) -> int:
-    """Largest divisor of ``rows`` <= ``cap`` — the per-grid-step row
-    count (full rows only: blocks never straddle a tile)."""
-    t = min(cap, max(rows, 1))
-    while rows % t:
-        t -= 1
-    return t
+    """Per-grid-step row count: all ``rows`` when they fit in ``cap``,
+    else the largest multiple of 8 <= ``cap`` dividing ``rows``, else
+    ``cap`` with a partial last block (``pl.cdiv`` grid).
+
+    Mosaic tiles the second-to-last block dim by 8, so a block must be a
+    multiple of 8 rows or the whole array.  Rows are independent, so the
+    padded rows of a partial last block never reach a real output."""
+    if rows <= cap:
+        return max(rows, 1)
+    for t in range(cap - cap % 8, 7, -8):
+        if rows % t == 0:
+            return t
+    return cap - cap % 8
 
 
 def _encode_kernel(x_ref, q_ref, s_ref, *, nb: int, b: int, wire_dtype: str):
@@ -98,17 +105,20 @@ def encode_fused(x, wire_dtype: str, *, interpret: bool = False):
     x2 = x.reshape(rows, d)
     rt = _row_tile(rows)
     qdt = payload_dtype(wire_dtype)
+    # inside a checked shard_map (the pipeline hop) the outputs vary over
+    # the same manual axes as the input
+    vma = jax.typeof(x).vma
     q, s = pl.pallas_call(
         functools.partial(_encode_kernel, nb=nb, b=b, wire_dtype=wire_dtype),
-        grid=(rows // rt,),
+        grid=(pl.cdiv(rows, rt),),
         in_specs=[pl.BlockSpec((rt, d), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((rt, nb, b), lambda i: (i, 0, 0)),
             pl.BlockSpec((rt, nb, 1), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((rows, nb, b), qdt),
-            jax.ShapeDtypeStruct((rows, nb, 1), jnp.float32),
+            jax.ShapeDtypeStruct((rows, nb, b), qdt, vma=vma),
+            jax.ShapeDtypeStruct((rows, nb, 1), jnp.float32, vma=vma),
         ],
         interpret=interpret,
     )(x2)
@@ -127,13 +137,14 @@ def decode_fused(q, scale, out_dtype, *, interpret: bool = False):
     rt = _row_tile(rows)
     out = pl.pallas_call(
         functools.partial(_decode_kernel, out_dtype=odt),
-        grid=(rows // rt,),
+        grid=(pl.cdiv(rows, rt),),
         in_specs=[
             pl.BlockSpec((rt, nb, b), lambda i: (i, 0, 0)),
             pl.BlockSpec((rt, nb, 1), lambda i: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((rt, nb * b), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, nb * b), odt),
+        out_shape=jax.ShapeDtypeStruct((rows, nb * b), odt,
+                                       vma=jax.typeof(q).vma),
         interpret=interpret,
     )(q2, s2)
     return out.reshape(lead + (nb * b,))

@@ -1,8 +1,7 @@
 """Production mesh construction.
 
 A function, not a module-level constant: importing this module never touches
-jax device state (the dry-run sets XLA_FLAGS before any jax init).  All
-version differences (axis types existing or not) live in parallel/compat.py.
+jax device state (the dry-run sets XLA_FLAGS before any jax init).
 """
 from __future__ import annotations
 

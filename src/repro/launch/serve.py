@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_arch
+from repro.configs import SIZES, get_arch
 from repro.launch.plan_args import add_plan_args
 from repro.models.lm import LM
 from repro.parallel.steps import init_serve_state, make_decode_step
@@ -103,7 +103,7 @@ def _static_serve(model, params, prompts, frames, args, cache_len):
     return toks
 
 
-def _request_mix(cfg, args) -> list:
+def request_mix(cfg, args) -> list:
     """Deterministic request set: ``--requests`` prompts of
     ``--prompt-len`` tokens, generation budgets cycled from ``--gen-mix``
     through a seeded shuffle (ragged on purpose — the convoy tax)."""
@@ -182,7 +182,7 @@ def _continuous_serve(model, params, args, cache_len):
     from repro.serving.engine import ServingEngine, convoy_units
 
     slots, plan = _resolve_slots(model, params, args, cache_len)
-    requests = _request_mix(model.cfg, args)
+    requests = request_mix(model.cfg, args)
     engine = ServingEngine(
         model, params, slots=slots, cache_len=cache_len,
         temperature=args.temperature, seed=args.seed,
@@ -244,10 +244,10 @@ def _split_serve(model, params, prompts, args, cache_len):
     return res["tokens"]
 
 
-def main(argv=None):
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--size", default="smoke", choices=["smoke", "full"])
+    ap.add_argument("--size", default="smoke", choices=SIZES)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=32)
@@ -279,10 +279,17 @@ def main(argv=None):
                     help="L>0: split inference — UE runs blocks[:L], "
                          "ships coded INFER frames over loopback")
     add_plan_args(ap, flavor="serve")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    spec = get_arch(args.arch)
-    cfg = spec.smoke if args.size == "smoke" else spec.full
+
+def main(argv=None):
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
+    args = parse_args(argv)
+    try:
+        cfg = get_arch(args.arch).config(args.size)
+    except ValueError as e:
+        raise SystemExit(f"--size {args.size}: {e}")
     model = LM(cfg)
     params = model.init(jax.random.key(args.seed))
     cache_len = args.cache_len or (args.prompt_len + args.gen)

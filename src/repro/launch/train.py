@@ -6,6 +6,9 @@ Examples
 PYTHONPATH=src python -m repro.launch.train --arch qwen1.5-4b --size smoke \
     --steps 200 --batch 16 --seq 64
 
+# one TPU chip's share of the published model (configs/qwen15_4b.py):
+... --size chip --batch 8 --seq 4096 --microbatches 8
+
 # the paper's C2P2SL k-microbatch gradient accumulation:
 ... --microbatches 8
 
@@ -22,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_arch
+from repro.configs import SIZES, get_arch
 from repro.data import TokenTaskConfig, token_batches
 from repro.models.lm import LM
 from repro.parallel.steps import make_lm_train_step
@@ -204,10 +207,32 @@ def resolve_pipeline_plan(*, pipeline_stages: int, pipeline_k,
                   "plan": plan.to_dict()}
 
 
+def place_pipeline_state(state, mesh):
+    """Put each stage's blocks (and their optimizer and error-feedback
+    state) on that stage's devices before the first step; everything
+    else is replicated over the pod axis."""
+    from repro.parallel.compat import NamedSharding, PartitionSpec as P
+    from repro.parallel.sharding import ShardingPolicy
+    shardings = ShardingPolicy(
+        mesh, pod_is_pipeline=True).train_state_shardings(state)
+    if "wire_ef" in state:     # [S, ticks, mb, seq, d]: one slot per stage
+        shardings["wire_ef"] = NamedSharding(mesh, P("pod"))
+    return jax.device_put(state, shardings)
+
+
 def main(argv=None):
+    """CLI entry point; returns the logged metric rows."""
+    return run(argv)[0]
+
+
+def run(argv=None):
+    """Parse ``argv`` and train; returns ``(logged metric rows, final
+    train state)``."""
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--size", default="smoke", choices=["smoke", "full"])
+    ap.add_argument("--size", default="smoke", choices=SIZES)
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=16)
     ap.add_argument("--seq", type=int, default=64)
@@ -235,8 +260,10 @@ def main(argv=None):
     ap.add_argument("--metrics-out", default=None)
     args = ap.parse_args(argv)
 
-    spec = get_arch(args.arch)
-    cfg = spec.smoke if args.size == "smoke" else spec.full
+    try:
+        cfg = get_arch(args.arch).config(args.size)
+    except ValueError as e:
+        raise SystemExit(f"--size {args.size}: {e}")
     model = LM(cfg)
     params = model.init(jax.random.key(args.seed))
     opt = adamw(cosine_schedule(args.lr, warmup=20, total=args.steps),
@@ -294,6 +321,7 @@ def main(argv=None):
                 "micro-batches with --pipeline-k instead")
         from repro.launch.mesh import make_host_mesh
         mesh = make_host_mesh(pod=args.pipeline_stages)
+        state = place_pipeline_state(state, mesh)
         line = (f"pipeline: S={pipeline.num_stages} "
                 f"k={pipeline.microbatches} [{plan_info['k_source']}] "
                 f"v={pipeline.virtual_stages} [{plan_info['v_source']}] "
@@ -376,8 +404,11 @@ def main(argv=None):
                 step_fn = cell_cache.get(switch.new)
                 warm = False
         if args.log_every and (i + 1) % args.log_every == 0:
-            row = {k: float(v) for k, v in mets.items()}
-            row.update(step=i + 1, wall_s=time.perf_counter() - t0)
+            row = {k: float(v) for k, v in mets.items()}  # waits for it
+            # step_s: this step's dispatch to its metrics on the host
+            # (compile included on the first step of a cell)
+            row.update(step=i + 1, step_s=time.perf_counter() - ts,
+                       wall_s=time.perf_counter() - t0)
             history.append(row)
             print(f"step {i+1:5d}  loss {row['loss']:.4f}  "
                   f"wall {row['wall_s']:.1f}s", flush=True)
@@ -397,7 +428,7 @@ def main(argv=None):
     if args.metrics_out:
         with open(args.metrics_out, "w") as f:
             json.dump(history, f, indent=1)
-    return history
+    return history, state
 
 
 if __name__ == "__main__":

@@ -16,8 +16,7 @@ def match_vma(val, ref):
     """Give ``val`` (a freshly-created scan carry) the same varying-manual-
     axes as ``ref`` — required when model code runs inside a partial-manual
     shard_map (the C2P2SL pod pipeline), where zero-initialized carries are
-    otherwise 'unvarying' and scan rejects the carry type mismatch.  The
-    version handling lives in parallel/compat.py (no-op on legacy JAX)."""
+    otherwise 'unvarying' and scan rejects the carry type mismatch."""
     from repro.parallel.compat import match_vma as _match_vma
     return _match_vma(val, ref)
 

@@ -1,258 +1,82 @@
-"""Version-portable parallel runtime primitives — the single choke point.
+"""The parallel runtime's JAX surface: meshes, shard_map, varying marks.
 
-Every JAX-version probe in this repo lives HERE.  Model / launch / analysis
-code never does ``hasattr(jax, ...)``; it imports from this module and either
-gets the new-API behaviour, a semantically-equivalent fallback, or a
-``CompatError`` naming the missing capability.
+The repo runs on one JAX generation, the explicit-sharding one (top-level
+``jax.shard_map`` with ``axis_names``/``check_vma``, typed meshes,
+``jax.set_mesh``, ``jax.lax.pcast``); docs/compat.md names the installed
+version.  Model, launch and analysis code import these helpers and the
+sharding types from here, so an API move touches one file.
 
-Supported range (see docs/compat.md):
-
-  * **legacy** — jax 0.4.3x: ``shard_map`` lives in ``jax.experimental``,
-    meshes have no axis types, ``Compiled.cost_analysis()`` returns a *list*
-    of per-program dicts, and there is no ``pcast``/``set_mesh``.  Crucially,
-    Manual-over-a-subset-of-axes shard_map (``auto=...``) aborts inside the
-    XLA SPMD partitioner on this generation, so the pipeline runs its
-    fully-manual path (see ``parallel/pipeline.py``).
-  * **explicit-sharding** — jax >= 0.6/0.7: top-level ``jax.shard_map`` with
-    ``axis_names``/``check_vma``, ``jax.sharding.AxisType`` meshes,
-    ``jax.set_mesh``, ``jax.lax.pcast`` varying marking, dict-valued
-    ``cost_analysis()``.
-
-The probe is attribute-based and runs once at import; nothing here touches
-device state (the dry-run sets XLA_FLAGS before any jax init).
+Nothing here touches device state at import (the dry-run sets XLA_FLAGS
+before any jax init).
 """
 from __future__ import annotations
 
-import dataclasses
-
 import jax
 # Re-exported so parallel/launch modules have one import site for sharding
-# types (keeps the version boundary in this file).
+# types.
 from jax.sharding import Mesh, NamedSharding, PartitionSpec  # noqa: F401
 
 
-class CompatError(NotImplementedError):
-    """A genuinely unsupported path on the installed JAX."""
-
-
-@dataclasses.dataclass(frozen=True)
-class Capabilities:
-    """What the installed JAX can do (probed once at import)."""
-
-    jax_version: tuple
-    axis_types: bool          # jax.sharding.AxisType / make_mesh(axis_types=)
-    toplevel_shard_map: bool  # jax.shard_map (vs jax.experimental.shard_map)
-    set_mesh: bool            # jax.set_mesh context manager
-    pcast: bool               # jax.lax.pcast varying-axis marking
-    partial_manual: bool      # shard_map Manual over a SUBSET of mesh axes
-                              # with GSPMD-auto on the rest
-
-
-def _version_tuple(v: str) -> tuple:
-    parts = []
-    for p in v.split("."):
-        digits = "".join(ch for ch in p if ch.isdigit())
-        if not digits:
-            break
-        parts.append(int(digits))
-    return tuple(parts)
-
-
-def _probe() -> Capabilities:
-    axis_types = hasattr(jax.sharding, "AxisType")
-    toplevel = hasattr(jax, "shard_map")
-    set_mesh = hasattr(jax, "set_mesh")
-    pcast = hasattr(jax.lax, "pcast")
-    # Partial-manual needs the whole explicit-sharding stack: the legacy
-    # shard_map has an ``auto=`` escape hatch, but on the 0.4.x partitioner
-    # it hard-aborts (Check failed: sharding.IsManualSubgroup()), so we gate
-    # on the API generation rather than the keyword's existence.
-    partial_manual = toplevel and axis_types and pcast
-    return Capabilities(
-        jax_version=_version_tuple(jax.__version__),
-        axis_types=axis_types,
-        toplevel_shard_map=toplevel,
-        set_mesh=set_mesh,
-        pcast=pcast,
-        partial_manual=partial_manual,
-    )
-
-
-CAPS = _probe()
-
-
-def require(flag: bool, feature: str, hint: str = "") -> None:
-    if not flag:
-        msg = (f"{feature} is not supported on installed jax "
-               f"{jax.__version__}")
-        if hint:
-            msg += f" — {hint}"
-        raise CompatError(msg)
-
-
-# ---------------- mesh construction / entry ----------------
-
-
-def auto_axis_types(n: int):
-    """axis_types tuple for an all-Auto mesh, or None when meshes are
-    untyped on this JAX."""
-    if CAPS.axis_types:
-        return (jax.sharding.AxisType.Auto,) * n
-    return None
+def _auto(n: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_mesh(axis_shapes, axis_names, *, axis_types=None, devices=None):
-    """``jax.make_mesh`` that tolerates untyped (legacy) meshes.
-
-    ``axis_types`` defaults to all-Auto on JAX that has axis types and is
-    ignored (with no semantic change: untyped meshes are GSPMD-auto) on
-    legacy JAX.
-    """
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if CAPS.axis_types:
-        if axis_types is None:
-            axis_types = auto_axis_types(len(tuple(axis_shapes)))
-        kwargs["axis_types"] = axis_types
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` with all-Auto axis types unless ``axis_types``
+    says otherwise."""
+    shapes = tuple(axis_shapes)
+    kwargs = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(shapes, tuple(axis_names),
+                         axis_types=axis_types or _auto(len(shapes)),
+                         **kwargs)
 
 
 def abstract_mesh(axis_shapes, axis_names, *, axis_types=None):
-    """Device-free mesh for spec/feasibility math.
-
-    New JAX: ``AbstractMesh(shapes, names[, axis_types])``.  Legacy JAX
-    takes a single tuple of ``(name, size)`` pairs and has no axis types.
-    """
+    """Device-free mesh for spec/feasibility math and jaxpr tracing."""
     shapes = tuple(axis_shapes)
-    names = tuple(axis_names)
-    if CAPS.axis_types:
-        if axis_types is None:
-            axis_types = auto_axis_types(len(shapes))
-        return jax.sharding.AbstractMesh(shapes, names,
-                                         axis_types=axis_types)
-    return jax.sharding.AbstractMesh(tuple(zip(names, shapes)))
+    return jax.sharding.AbstractMesh(
+        shapes, tuple(axis_names), axis_types=axis_types or _auto(len(shapes)))
 
 
 def mesh_context(mesh):
-    """Enter a mesh context: ``jax.set_mesh`` on new JAX, the ``Mesh``
-    context manager on legacy JAX (both make the mesh ambient for
-    spec-only sharding annotations)."""
-    if CAPS.set_mesh:
-        return jax.set_mesh(mesh)
-    return mesh  # legacy Mesh is itself a context manager
-
-
-# ---------------- shard_map ----------------
+    """Make ``mesh`` ambient for spec-only sharding annotations."""
+    return jax.set_mesh(mesh)
 
 
 def shard_map(f, mesh, in_specs, out_specs, *, manual_axes=None,
               check: bool = False):
-    """Portable ``shard_map``.
-
-    ``manual_axes``: iterable of mesh axis names to run Manual over; None
-    means all axes (the classic fully-manual region).  Partial-manual
-    (a strict subset) is only available on explicit-sharding JAX — callers
-    must branch on ``CAPS.partial_manual`` and restructure to fully-manual
-    on legacy JAX (see ``parallel/pipeline.py`` for the pattern).
-
-    ``check``: replication/varying checking (``check_vma`` on new JAX).
-    Legacy shard_map always runs with ``check_rep=False`` because the code
-    written against this wrapper cannot mark varying axes (no ``pcast``).
-    """
-    if CAPS.toplevel_shard_map:
-        kwargs = {"check_vma": check}
-        if manual_axes is not None:
-            kwargs["axis_names"] = set(manual_axes)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    if manual_axes is not None and set(manual_axes) != set(mesh.axis_names):
-        raise CompatError(
-            f"partial-manual shard_map over {sorted(manual_axes)} (mesh axes "
-            f"{sorted(mesh.axis_names)}) is unsupported on installed jax "
-            f"{jax.__version__}; restructure to a fully-manual region "
-            "(branch on compat.CAPS.partial_manual)")
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-    return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
-
-
-# ---------------- varying-axis marking ----------------
+    """``jax.shard_map``, Manual over ``manual_axes`` (None: every mesh
+    axis) with GSPMD-auto on the rest; ``check`` is ``check_vma``."""
+    kwargs = {"check_vma": check}
+    if manual_axes is not None:
+        kwargs["axis_names"] = set(manual_axes)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def mark_varying(x, axes):
-    """Mark ``x`` as varying over manual ``axes`` (new-JAX ``pcast``).
-
-    Legacy shard_map runs with replication checking off, where every value
-    is implicitly per-device — marking is a no-op there.
-    """
-    if CAPS.pcast:
-        return jax.lax.pcast(x, tuple(axes), to="varying")
-    return x
+    """Mark ``x`` as varying over the manual ``axes``."""
+    return jax.lax.pcast(x, tuple(axes), to="varying")
 
 
 def match_vma(val, ref):
-    """Give ``val`` (e.g. a freshly-created scan carry) the same
-    varying-manual-axes as ``ref`` — required inside partial-manual
-    shard_map regions where zero-initialized carries are otherwise
-    'unvarying' and scan rejects the carry-type mismatch.  No-op on
-    legacy JAX (no varying types)."""
-    if not CAPS.pcast:
-        return val
-    try:
-        want = set(jax.typeof(ref).vma)
-        have = set(jax.typeof(val).vma)
-        missing = tuple(sorted(want - have))
-        if missing:
-            return jax.lax.pcast(val, missing, to="varying")
-    except (AttributeError, TypeError, ValueError):
-        pass
+    """Give ``val`` (e.g. a freshly-created scan carry) the
+    varying-manual-axes of ``ref`` — inside a partial-manual shard_map a
+    zero-initialized carry is otherwise 'unvarying' and scan rejects the
+    carry-type mismatch."""
+    missing = tuple(sorted(set(jax.typeof(ref).vma)
+                           - set(jax.typeof(val).vma)))
+    if missing:
+        return jax.lax.pcast(val, missing, to="varying")
     return val
 
 
 def auto_axes_sharding(mesh, manual_axes, spec):
     """A NamedSharding usable for ``with_sharding_constraint`` INSIDE a
     partial-manual region: the mesh view has ``manual_axes`` Manual and
-    everything else Auto.  Only meaningful (and only constructible) on
-    explicit-sharding JAX."""
-    require(CAPS.partial_manual, "constraints inside a partial-manual region",
-            "legacy pipelines shard explicitly instead (see pipeline.py)")
-    manual = set(manual_axes) if not isinstance(manual_axes, str) \
-        else {manual_axes}
+    everything else Auto."""
     AxisType = jax.sharding.AxisType
     abs_mesh = mesh.abstract_mesh.update(axis_types=tuple(
-        AxisType.Manual if n in manual else AxisType.Auto
+        AxisType.Manual if n in manual_axes else AxisType.Auto
         for n in mesh.shape))
     return NamedSharding(abs_mesh, spec)
-
-
-# ---------------- compiled-executable introspection ----------------
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` normalized to one flat dict.
-
-    Legacy JAX returns a *list* of per-program dicts (usually length 1);
-    new JAX returns the dict directly.  Numeric entries are summed across
-    list elements; missing/unavailable analysis yields ``{}``.
-    """
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:  # backend without cost analysis
-        return {}
-    if ca is None:
-        return {}
-    if isinstance(ca, dict):
-        return dict(ca)
-    out: dict = {}
-    if isinstance(ca, (list, tuple)):
-        for entry in ca:
-            if not isinstance(entry, dict):
-                continue
-            for key, val in entry.items():
-                if isinstance(val, (int, float)) and isinstance(
-                        out.get(key, 0.0), (int, float)):
-                    out[key] = out.get(key, 0.0) + float(val)
-                else:
-                    out[key] = val
-    return out

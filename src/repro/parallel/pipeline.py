@@ -39,18 +39,10 @@ the scan — the transpose of a cyclic ppermute is the reverse cyclic
 ppermute, and the transpose of the per-tick chunk gather is the
 scatter-add into the right chunk's weight gradient.
 
-Version portability (all probing in ``parallel/compat.py``):
-
-  * On explicit-sharding JAX the region is Manual over 'pod' ONLY —
-    data/model axes stay GSPMD-auto inside the stage, with an explicit
-    constraint anchoring the micro-batch to the data axis (without it
-    GSPMD replicates the micro-batch across the 16-wide data axis — 16x
-    redundant compute; EXPERIMENTS.md §Perf, pipeline iteration 1).
-  * On legacy JAX (0.4.x) Manual-over-a-subset aborts inside the XLA SPMD
-    partitioner, so the region is fully manual: the micro-batch dim is
-    explicitly sharded over 'data' (when divisible) and stage weights are
-    replicated over the remaining axes.  Numerically identical; the model
-    axis does redundant compute inside pipeline stages on that generation.
+The region is Manual over 'pod' ONLY — data/model axes stay GSPMD-auto
+inside the stage, with an explicit constraint anchoring the micro-batch
+to the data axis (without it GSPMD replicates the micro-batch across the
+data axis: redundant compute on every data shard).
 
 Embedding and LM head run replicated across pods (negligible FLOP share);
 the ppermuted tensor is the cut-layer activation — the paper's ``s_l``.
@@ -60,8 +52,7 @@ the ppermuted tensor is the cut-layer activation — the paper's ``s_l``.
 activation before each forward ppermute and the activation gradient on
 the transposed backward ppermute — EPSL's payload compression applied to
 the pod boundary — while ``"none"`` keeps the raw ppermute bit-for-bit.
-The codec wraps the hop only; both shard_map lowerings share it through
-``_tick_loop``.
+The codec wraps the hop only, inside ``_tick_loop``.
 """
 from __future__ import annotations
 
@@ -313,17 +304,15 @@ def pipeline_blocks(cfg, blocks, xs, positions, spec: PipelineSpec, *,
     else:
         wire_ef = None
     staged = _split_stages(blocks, spec.num_stages, spec.virtual_stages)
-    run = (_pipeline_partial_manual if compat.CAPS.partial_manual
-           else _pipeline_full_manual)
-    outs, auxes = run(cfg, staged, xs, positions, spec, mesh,
-                      prefix_len, enc_outs, wire_ef)
+    outs, auxes = _pipeline(cfg, staged, xs, positions, spec, mesh,
+                            prefix_len, enc_outs, wire_ef)
     # last stage's real outputs; aux summed over stages (each owns its own
     # layers' aux), averaged over micro-batches
     return outs[-1], auxes.sum() / k
 
 
 def _stage_scan_fn(cfg, spec, positions, prefix_len):
-    """One stage's block scan on one micro-batch (shared by both paths)."""
+    """One stage's block scan on one micro-batch."""
     kind = cfg.layer_kinds[0]
 
     def stage_scan(blocks_local, x, enc_out, pin):
@@ -341,8 +330,7 @@ def _stage_scan_fn(cfg, spec, positions, prefix_len):
 
 def _tick_loop(spec, stage, k, xs_full, enc_full, state0, aux0, run_stage,
                wire_ef=None):
-    """The (interleaved) 1F1B tick schedule shared by both shard_map
-    flavours.
+    """The (interleaved) 1F1B tick schedule of one stage.
 
     ``wire_ef`` (top-k codecs only) is this stage's error-feedback buffer
     [ticks, mb, seq, d] f32, entering the scan as per-tick xs so each
@@ -442,15 +430,21 @@ def _chunk_picker(blocks_local, virtual_stages: int):
         blocks_local)
 
 
-def _pipeline_partial_manual(cfg, staged, xs, positions, spec, mesh,
-                             prefix_len, enc_outs, wire_ef=None):
-    """Explicit-sharding JAX: Manual over 'pod' only, data/model auto."""
+def _pipeline(cfg, staged, xs, positions, spec, mesh, prefix_len, enc_outs,
+              wire_ef=None):
+    """The shard_map region: Manual over 'pod' (and size-1 axes),
+    data/model auto."""
     k = xs.shape[0]
     # micro-batch over data; seq deliberately NOT model-sharded inside the
     # stage: per-micro-batch SP re-gathers the stage weights and re-reduces
     # weight grads k times (refuted, EXPERIMENTS.md §Perf pipeline it2) —
     # without SP, GSPMD defers the weight-grad reduction across ticks.
-    data_sharding = compat.auto_axes_sharding(mesh, spec.axis, P("data"))
+    # Axes of size 1 partition nothing: they run Manual with the pod axis,
+    # so on a pipeline-only mesh the region is fully manual and Mosaic
+    # kernels (the fused wire codec) can lower inside it.
+    manual = {spec.axis} | {a for a, n in mesh.shape.items() if n == 1}
+    data = None if "data" in manual or "data" not in mesh.shape else "data"
+    data_sharding = compat.auto_axes_sharding(mesh, manual, P(data))
 
     def pin(x):
         """Anchor the micro-batch dim to the data axis INSIDE the manual-
@@ -465,6 +459,13 @@ def _pipeline_partial_manual(cfg, staged, xs, positions, spec, mesh,
         blocks_local = jax.tree.map(lambda a: a[0], blocks_stage)
         pick = _chunk_picker(blocks_local, spec.virtual_stages)
         stage = jax.lax.axis_index(spec.axis)
+        # The replicated micro-batches (and encoder memory) meet stage-
+        # varying values inside the tick loop.  Mark them varying HERE, at
+        # entry: an implicit cast inside the loop transposes to a cross-pod
+        # psum on every backward tick; at entry it is one psum per step.
+        xs_full = compat.mark_varying(xs_full, (spec.axis,))
+        if enc_full is not None:
+            enc_full = compat.mark_varying(enc_full, (spec.axis,))
         # carries differ per stage -> mark them varying over the pod axis
         state = compat.mark_varying(
             jnp.zeros(xs_full.shape[1:], xs_full.dtype), (spec.axis,))
@@ -475,7 +476,7 @@ def _pipeline_partial_manual(cfg, staged, xs, positions, spec, mesh,
             # micro-batch dim to the data axis like every other carry
             ef_local = jax.lax.with_sharding_constraint(
                 ef_full[0],
-                compat.auto_axes_sharding(mesh, spec.axis, P(None, "data")))
+                compat.auto_axes_sharding(mesh, manual, P(None, data)))
         out, aux_acc = _tick_loop(
             spec, stage, k, xs_full, enc_full, state, aux0,
             lambda cur, enc, j: stage_scan(pick(j), cur, enc, pin),
@@ -506,78 +507,7 @@ def _pipeline_partial_manual(cfg, staged, xs, positions, spec, mesh,
         body, mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(spec.axis), P(spec.axis)),
-        manual_axes={spec.axis}, check=True)
-    return fn(*args)
-
-
-def _pipeline_full_manual(cfg, staged, xs, positions, spec, mesh,
-                          prefix_len, enc_outs, wire_ef=None):
-    """Legacy JAX: fully-manual region (partial-manual aborts in the 0.4.x
-    SPMD partitioner).
-
-    The micro-batch dim is explicitly sharded over 'data' when divisible
-    (each data shard runs the same pipeline on its slice; weight grads are
-    psum'ed by the shard_map transpose); otherwise — and over the 'model'
-    axis always — compute inside stages is replicated.  The stage index
-    arrives as a pod-sharded ``arange`` input because ``axis_index``
-    lowers to an SPMD-unsupported partition-id on this generation.
-    """
-    k, mb = xs.shape[0], xs.shape[1]
-    other_axes = tuple(n for n in mesh.axis_names if n != spec.axis)
-    n_data = mesh.shape.get("data", 1)
-    data_axis = "data" if ("data" in mesh.shape and n_data > 1
-                           and mb % n_data == 0) else None
-    mb_spec = P(None, data_axis)   # [k, mb, ...] leaves
-
-    stage_scan = _stage_scan_fn(cfg, spec, positions, prefix_len)
-
-    def per_stage(stage_ids, blocks_stage, xs_full, pos, enc_full,
-                  ef_full):
-        del pos  # replicated copy of ``positions`` (kept as an explicit
-        # argument: legacy shard_map cannot close over traced values)
-        blocks_local = jax.tree.map(lambda a: a[0], blocks_stage)
-        pick = _chunk_picker(blocks_local, spec.virtual_stages)
-        stage = stage_ids[0]
-        state = jnp.zeros(xs_full.shape[1:], xs_full.dtype)
-        aux0 = jnp.float32(0.0)
-        ef_local = None if ef_full is None else ef_full[0]
-        out, aux_acc = _tick_loop(
-            spec, stage, k, xs_full, enc_full, state, aux0,
-            lambda cur, enc, j: stage_scan(pick(j), cur, enc,
-                                           lambda y: y),
-            wire_ef=ef_local)
-        if other_axes:
-            # per-data-slice aux -> batch mean (replicated axes unchanged)
-            aux_acc = jax.lax.pmean(aux_acc, other_axes)
-        return out[None], aux_acc[None]
-
-    stage_ids = jnp.arange(spec.num_stages, dtype=jnp.int32)
-    args = [stage_ids, staged, xs, positions]
-    in_specs = [P(spec.axis), P(spec.axis), mb_spec, P()]
-    if enc_outs is not None:
-        args.append(enc_outs)
-        in_specs.append(mb_spec)
-    if wire_ef is not None:
-        # [S, ticks, mb, seq, d]: stage dim manual over pod, micro-batch
-        # dim sharded over data exactly like the xs micro-batches
-        args.append(wire_ef)
-        in_specs.append(P(spec.axis, None, data_axis))
-
-    def body(*a):
-        i = 4
-        enc_full = ef_full = None
-        if enc_outs is not None:
-            enc_full = a[i]
-            i += 1
-        if wire_ef is not None:
-            ef_full = a[i]
-        return per_stage(a[0], a[1], a[2], a[3], enc_full, ef_full)
-
-    fn = compat.shard_map(
-        body, mesh,
-        in_specs=tuple(in_specs),
-        out_specs=(P(spec.axis, None, data_axis), P(spec.axis)),
-        check=False)
+        manual_axes=manual, check=True)
     return fn(*args)
 
 
@@ -603,8 +533,8 @@ def make_pipelined_loss(model, spec: PipelineSpec, mesh=None):
     assert k >= 1, f"microbatches k={k} must be >= 1"
 
     def _loss(params, batch, wire_ef):
-        # Plain-JAX context inside: data/model axes are GSPMD-auto (or
-        # replicated on legacy JAX), the pipeline shard_map owns 'pod'.
+        # Plain-JAX context inside: data/model axes are GSPMD-auto, the
+        # pipeline shard_map owns 'pod'.
         from repro.parallel.context import get_ctx
         use_mesh = mesh if mesh is not None else get_ctx().mesh
         with use_ctx(ParallelCtx()):

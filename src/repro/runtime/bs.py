@@ -91,6 +91,10 @@ class BSDispatcher:
     async def close(self):
         if self._server is not None:
             self._server.close()
+            # a reader blocked on a full inbox never sees its client leave,
+            # so drop every connection here before waiting for them
+            for _inbox, writer in self._clients.values():
+                writer.close()
             await self._server.wait_closed()
 
     def _observe_hop(self, nbytes, seconds):
@@ -107,9 +111,17 @@ class BSDispatcher:
                           frame.meta.get("dl_s"))
 
     async def _handle_client(self, reader, writer):
+        # Close our end when the client leaves: ``Server.wait_closed()``
+        # (Python >= 3.12.1) waits for every connection, so an open writer
+        # would hang ``close()``.
+        try:
+            await self._serve_client(reader, writer)
+        finally:
+            writer.close()
+
+    async def _serve_client(self, reader, writer):
         hello = await protocol.read_frame(reader)
         if hello.ftype != protocol.HELLO:
-            writer.close()
             raise ValueError(
                 f"client handshake must be HELLO, got ftype={hello.ftype}")
         cid = hello.client

@@ -275,20 +275,21 @@ class BSInferServer:
         import jax.numpy as jnp
 
         from repro.runtime import protocol
-        hello = await protocol.read_frame(reader)
-        if hello.ftype != protocol.HELLO:
-            writer.close()
-            raise ValueError(
-                f"handshake must be HELLO, got ftype={hello.ftype}")
-        if hello.meta.get("wire_dtype", self.wire_dtype) != self.wire_dtype:
-            writer.close()
-            raise ValueError(
-                f"client codec {hello.meta.get('wire_dtype')!r} != server "
-                f"{self.wire_dtype!r}")
-        cid = hello.client
         cache = None
         position = None
+        # every exit closes our end: ``Server.wait_closed()`` (Python >=
+        # 3.12.1) waits for every connection to drop
         try:
+            hello = await protocol.read_frame(reader)
+            if hello.ftype != protocol.HELLO:
+                raise ValueError(
+                    f"handshake must be HELLO, got ftype={hello.ftype}")
+            if hello.meta.get("wire_dtype",
+                              self.wire_dtype) != self.wire_dtype:
+                raise ValueError(
+                    f"client codec {hello.meta.get('wire_dtype')!r} != "
+                    f"server {self.wire_dtype!r}")
+            cid = hello.client
             while True:
                 frame = await protocol.read_frame(reader)
                 if frame.ftype == protocol.BYE:

@@ -22,8 +22,7 @@ def test_scan_trip_count_correction():
     c = _compile(lambda x, W: jax.lax.scan(body, x, W)[0], x, W)
     res = analyze(c.as_text())
     assert res["flops"] == pytest.approx(8 * 2 * 4 * 256 * 256)
-    # the flat XLA number misses the trip count (the bug we correct);
-    # flat_cost_analysis normalizes the list-vs-dict return across versions
+    # the flat XLA number misses the trip count (the bug we correct)
     flat = float(flat_cost_analysis(c).get("flops", 0.0))
     assert flat < res["flops"] / 4
 

@@ -1,9 +1,10 @@
-"""Regression: HLO collective parsing against checked-in text from both
-pipeline lowerings, so the roofline's data source can't silently drift
-when JAX changes its HLO spelling.
+"""Regression: HLO collective parsing against checked-in text in two
+spellings, so the roofline's data source can't silently drift when JAX
+changes its HLO spelling.  The texts test the parser, not the JAX that
+wrote them.
 
-* ``hlo_legacy_0437.txt`` — captured from jax 0.4.37 / jaxlib 0.4.36
-  (the fully-manual shard_map path): synchronous collectives, explicit
+* ``hlo_legacy_0437.txt`` — captured from jax 0.4.37 / jaxlib 0.4.36 (a
+  fully-manual shard_map program): synchronous collectives, explicit
   ``replica_groups={{...}}`` lists, f32.
 * ``hlo_current.txt`` — the explicit-sharding generation's spelling
   (partial-manual path): async ``-start``/``-done`` pairs (whose result
@@ -96,3 +97,24 @@ def test_iota_replica_groups_cross_pod_detection():
     assert res2["coll_dcn_bytes"] > 0
     # at pod_size=4 all four devices share one pod -> nothing crosses
     assert res4["coll_dcn_bytes"] == 0
+
+
+def test_tpu_tiled_layout_tuple_parses():
+    """TPU layouts spell tiles inside the layout braces
+    (``{3,1,2,0:T(8,128)(4,1)S(1)}``), so an async ``-start`` tuple type
+    nests parentheses one level deep; the instruction must still parse,
+    or the hop it carries vanishes from the audit."""
+    layout = "{3,1,2,0:T(8,128)(4,1)S(1)}"
+    text = (
+        "ENTRY %main (p: s8[1,16,10,256]) -> s8[1,16,10,256] {\n"
+        f"  %p = s8[1,16,10,256]{layout} parameter(0)\n"
+        f"  %cp-start = (s8[1,16,10,256]{layout}, s8[1,16,10,256]{layout}, "
+        "u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%p), "
+        "channel_id=1, source_target_pairs={{1,0},{2,1},{3,2}}\n"
+        f"  ROOT %cp-done = s8[1,16,10,256]{layout} "
+        "collective-permute-done(%cp-start)\n"
+        "}\n")
+    ops = [ins.opcode for ins in parse_hlo(text)["main"]]
+    assert ops == ["parameter", "collective-permute-start",
+                   "collective-permute-done"]
+    assert analyze(text)["coll_by_kind"]["collective-permute"] > 0

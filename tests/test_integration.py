@@ -137,3 +137,32 @@ def test_compress_grads_resumes_from_pre_flag_checkpoint(tmp_path):
     assert len(history) == 1                                # resumed at 1
     assert history[-1]["step"] == 2
     assert np.isfinite(history[-1]["loss"])
+
+
+@pytest.mark.parametrize("from_env", [False, True],
+                         ids=["unset", "set"])
+def test_compile_cache_dir_rule(monkeypatch, tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR set: JAX reads it itself and the helper
+    sets nothing.  Unset: the cache is the checkout's fixed .jax_cache/,
+    the same path on every call (the path is part of the cache key)."""
+    import os
+
+    from repro.launch import cache
+    before = jax.config.jax_compilation_cache_dir
+    untouched = str(tmp_path / "untouched")
+    jax.config.update("jax_compilation_cache_dir", untouched)
+    try:
+        if from_env:
+            monkeypatch.setenv(cache.ENV, str(tmp_path / "env"))
+            assert cache.use_compile_cache() == str(tmp_path / "env")
+            assert jax.config.jax_compilation_cache_dir == untouched
+        else:
+            monkeypatch.delenv(cache.ENV, raising=False)
+            root = os.path.dirname(os.path.dirname(os.path.abspath(
+                __file__)))
+            want = os.path.join(root, ".jax_cache")
+            assert cache.use_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+            assert cache.use_compile_cache() == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

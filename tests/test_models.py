@@ -181,6 +181,33 @@ def test_full_configs_match_assignment():
     assert get_arch("qwen3-moe-30b-a3b").full.moe_topk == 8
 
 
+def test_chip_config_keeps_published_widths():
+    """``chip`` is FULL cut only in the keys its module lists in
+    ``REDUCED`` (depth, vocabulary slice); every width is published."""
+    import dataclasses
+
+    from repro.configs import qwen15_4b
+    spec = get_arch("qwen1.5-4b")
+    full = dataclasses.asdict(spec.full)
+    chip = dataclasses.asdict(spec.config("chip"))
+    changed = {k for k in full if full[k] != chip[k]} - {"name"}
+    assert changed == set(qwen15_4b.REDUCED)
+    for key, (published, held, why) in qwen15_4b.REDUCED.items():
+        assert (full[key], chip[key]) == (published, held) and why
+
+
+def test_size_chip_without_chip_config_is_an_error():
+    """``--size chip`` on an architecture with no chip share stops; it
+    never falls back to another size."""
+    from repro.launch import serve, train
+    assert get_arch("rwkv6-3b").chip is None
+    with pytest.raises(ValueError, match="no chip-share config"):
+        get_arch("rwkv6-3b").config("chip")
+    for main in (train.main, serve.main):
+        with pytest.raises(SystemExit, match="--size chip"):
+            main(["--arch", "rwkv6-3b", "--size", "chip"])
+
+
 def test_shape_skips_documented():
     """8 long_500k cells skip with a reason; ssm/hybrid run it."""
     skips = [a for a in ARCH_NAMES

@@ -273,7 +273,11 @@ def test_planner_chosen_plan_matches_reference():
     """)
     res = json.loads(out.strip().splitlines()[-1])
     assert abs(res["loss_ref"] - res["loss_pipe"]) < 1e-6
-    assert res["gdiff"] < 1e-7
+    # float32: the pipeline sums each weight gradient over 13 micro-batches
+    # (plus the padded row) where the reference reduces the whole batch in
+    # one pass.  The orders differ, so an O(1) gradient entry may move by a
+    # few ulps (eps = 1.19e-7); 1e-6 is about eight ulps.
+    assert res["gdiff"] < 1e-6
 
 
 @pytest.mark.slow

@@ -322,8 +322,14 @@ def test_split_decode_composition_bitexact(model_params):
         np.random.default_rng(3).integers(0, CFG.vocab, (2, 5)), jnp.int32)
     split = SplitDecode(model, 1)
     ue_p, bs_p = split.split_params(params)
-    acts, ue_c = split.ue_prefill(ue_p, prompts, cache_len=12)
-    logits, bs_c = split.bs_prefill(bs_p, acts, cache_len=12)
+    # jitted on both sides, as the serving path runs them: eager op-by-op
+    # dispatch rounds some fused sums differently from a compiled program
+    prefill = jax.jit(lambda f, p, x: f(p, x, cache_len=12),
+                      static_argnums=0)
+    acts, ue_c = prefill(split.ue_prefill, ue_p, prompts)
+    logits, bs_c = prefill(split.bs_prefill, bs_p, acts)
+    ue_decode, bs_decode = jax.jit(split.ue_decode), jax.jit(split.bs_decode)
+    decode = jax.jit(model.decode_step)
     ml, ms = jax.jit(
         model.prefill_with_cache,
         static_argnames=("cache_len", "cache_dtype"))(
@@ -333,12 +339,9 @@ def test_split_decode_composition_bitexact(model_params):
     tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
     cache = ms["cache"]
     for pos in range(5, 8):
-        a, ue_c = split.ue_decode(ue_p, tok, ue_c,
-                                  jnp.asarray(pos, jnp.int32))
-        lg, bs_c = split.bs_decode(bs_p, a, bs_c,
-                                   jnp.asarray(pos, jnp.int32))
-        mlg, cache = model.decode_step(params, tok, cache,
-                                       jnp.asarray(pos, jnp.int32))
+        a, ue_c = ue_decode(ue_p, tok, ue_c, jnp.asarray(pos, jnp.int32))
+        lg, bs_c = bs_decode(bs_p, a, bs_c, jnp.asarray(pos, jnp.int32))
+        mlg, cache = decode(params, tok, cache, jnp.asarray(pos, jnp.int32))
         np.testing.assert_array_equal(np.asarray(lg), np.asarray(mlg))
         tok = jnp.argmax(lg, -1).astype(jnp.int32)[:, None]
 
@@ -361,13 +364,17 @@ def test_infer_loopback_wire_honesty(model_params, wire):
     match the planner's billed_hop_bytes within 1%; 'none' tokens are
     bit-identical to the monolithic greedy chain (quantized codecs are
     lossy by design — shape and completion only)."""
-    from repro.serving.infer import run_split_infer
+    import asyncio
+
+    from repro.serving.infer import _run_split_infer
     model, params = model_params
     prompts = np.random.default_rng(5).integers(
         0, CFG.vocab, (2, 4)).astype(np.int32)
     gen = 3
-    res = run_split_infer(model, params, cut=1, prompts=prompts, gen=gen,
-                          cache_len=8, wire_dtype=wire)
+    # a deadline of its own: a hang on the socket fails this test only
+    res = asyncio.run(asyncio.wait_for(_run_split_infer(
+        model, params, cut=1, prompts=prompts, gen=gen, cache_len=8,
+        wire_dtype=wire), 240))
     assert res["tokens"].shape == (2, gen)
     rel = abs(res["measured_payload_bytes"] - res["billed_payload_bytes"]) \
         / res["billed_payload_bytes"]

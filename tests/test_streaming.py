@@ -33,6 +33,14 @@ from repro.runtime.qos import QoSMonitor
 
 CODECS = ["none", "int8", "fp8", "int8+topk0.25"]
 
+#: Each loopback-socket scenario runs under its own deadline, so a hang on
+#: the socket fails that one test instead of stalling the whole run.
+SOCKET_TIMEOUT_S = 240
+
+
+def run_bounded(coro, timeout_s: float = SOCKET_TIMEOUT_S):
+    return asyncio.run(asyncio.wait_for(coro, timeout_s))
+
 
 def _tiny_cfg(d_model=32, vocab=64, num_layers=4):
     from repro.models import LMConfig
@@ -267,7 +275,7 @@ def test_bounded_inbox_backpressure():
         writer.close()
         await disp.close()
 
-    asyncio.run(scenario())
+    run_bounded(scenario())
 
 
 async def _stream(cfg, *, shapers, steps, wire_dtype="none", lr=1e-3,
@@ -320,9 +328,9 @@ def test_ragged_arrival_order_independence():
     from repro.wireless import LinkShaper
     cfg = _tiny_cfg()
     slow, fast = LinkShaper(2e5), None
-    d1, s1, _ = asyncio.run(_stream(cfg, shapers=[slow, fast, fast],
+    d1, s1, _ = run_bounded(_stream(cfg, shapers=[slow, fast, fast],
                                     steps=3))
-    d2, s2, _ = asyncio.run(_stream(cfg, shapers=[fast, fast, slow],
+    d2, s2, _ = run_bounded(_stream(cfg, shapers=[fast, fast, slow],
                                     steps=3))
     np.testing.assert_allclose(d1.losses, d2.losses, rtol=0, atol=1e-6)
     import jax
@@ -341,7 +349,7 @@ def test_wire_honesty_on_socket(wire):
     separately as overhead, mirroring ``hop_overhead_s``)."""
     from repro.runtime.driver import run_streaming
     cfg = _tiny_cfg(d_model=64, vocab=64)
-    res = asyncio.run(run_streaming(
+    res = run_bounded(run_streaming(
         cfg, cut=2, n_clients=2, steps=2, batch_per_client=2, seq=16,
         wire_dtype=wire))
     assert all(np.isfinite(res["losses"]))
@@ -397,7 +405,7 @@ def test_e2e_four_clients_matches_joint_training():
 
     cfg = _tiny_cfg()
     STEPS, N, BPC, SEQ, SEED, LR, CUT = 20, 4, 2, 16, 0, 1e-3, 2
-    res = asyncio.run(run_streaming(
+    res = run_bounded(run_streaming(
         cfg, cut=CUT, n_clients=N, steps=STEPS, batch_per_client=BPC,
         seq=SEQ, seed=SEED, wire_dtype="none", lr=LR))
     assert len(res["losses"]) == STEPS
@@ -479,7 +487,7 @@ def test_replanner_tracks_injected_delay_change():
             shaper.set_rate(bw0 / 4)
         asyncio.ensure_future(watch())
 
-    asyncio.run(run_streaming(
+    run_bounded(run_streaming(
         cfg, cut=2, n_clients=2, steps=10, batch_per_client=2, seq=16,
         seed=0, wire_dtype="none", lr=1e-3, shaper=shaper, replanner=rp,
         on_started=on_started))

@@ -54,7 +54,9 @@ def _bits_equal(a, b) -> bool:
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 @pytest.mark.parametrize("shape", [(3, 5, 384),    # ragged lead, block 192
                                    (15, 96),       # block == d_model == 96
-                                   (2, 4, 256)])   # block 256 regime
+                                   (2, 4, 256),    # block 256 regime
+                                   (17, 24, 64),   # 408 rows: 24-row tiles
+                                   (2, 65, 128)])  # 130 rows: partial block
 def test_fused_codec_bit_parity(wdt, dtype, shape):
     """The Pallas encode/decode (interpret mode off-TPU) must be BIT-
     identical to the jnp reference — same payload bytes, same fp32
@@ -75,6 +77,17 @@ def test_fused_codec_bit_parity(wdt, dtype, shape):
     yj, yf = dec_jnp(qj, sj), dec_fused(qj, sj)
     assert _bits_equal(yj, yf)
     assert yj.shape == shape and yj.dtype == jnp.dtype(dtype)
+
+
+@pytest.mark.parametrize("rows", [1, 15, 128, 130, 408, 4094, 4096, 8192])
+def test_row_tile_is_mosaic_legal(rows):
+    """A row block is the whole array or a multiple of 8 rows (Mosaic's
+    sublane tiling); the grid covers every row exactly once."""
+    from repro.kernels.wire_codec import _row_tile
+    t = _row_tile(rows)
+    assert t == rows or (t % 8 == 0 and t <= 128)
+    grid = -(-rows // t)
+    assert (grid - 1) * t < rows <= grid * t
 
 
 def test_fused_roundtrip_matches_reference_roundtrip():
