@@ -72,37 +72,22 @@ def _fail(msg: str):
     raise RuntimeError(msg)
 
 
-class CompileClock:
-    """Seconds JAX spends compiling or loading a program from the
-    persistent cache (its ``backend_compile_duration`` event), and how
-    many of those programs the cache held."""
+def run_phase(clock, name: str, fn, **kwargs):
+    """Run one phase; print its wall seconds, its compile seconds and
+    which programs it compiled or loaded from the persistent cache."""
+    before = clock.snapshot()
+    t0 = time.perf_counter()
+    out = fn(**kwargs)
+    wall = time.perf_counter() - t0
+    seconds, compiled, loaded = clock.since(before)
 
-    def __init__(self):
-        self.seconds, self.programs, self.cache_hits = 0.0, 0, 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-            self.programs += 1
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def run(self, name: str, fn, **kwargs):
-        """Run one phase; print its compile seconds and wall seconds."""
-        before = (self.seconds, self.programs, self.cache_hits)
-        t0 = time.perf_counter()
-        out = fn(**kwargs)
-        wall = time.perf_counter() - t0
-        s, n, h = (a - b for a, b in zip(
-            (self.seconds, self.programs, self.cache_hits), before))
-        print(f"{name}: passed in {wall:.2f} s wall; compile {s:.2f} s over "
-              f"{n} programs, {h} of them from the persistent cache",
-              flush=True)
-        return out
+    def names(counts):
+        return ", ".join(f"{n} x{c}" if c > 1 else n
+                         for n, c in sorted(counts.items())) or "none"
+    print(f"{name}: passed in {wall:.2f} s wall; compile {seconds:.2f} s; "
+          f"compiled: {names(compiled)}; from the persistent cache: "
+          f"{names(loaded)}", flush=True)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -458,16 +443,16 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    from repro.launch.cache import use_compile_cache
+    from repro.launch.cache import CompileClock, use_compile_cache
     print(f"compile cache: {use_compile_cache()}", flush=True)
     device = phase_device(args.chips)
     clock = CompileClock()
     if args.chips == 4:
-        clock.run("pipeline", phase_pipeline, seed=args.seed)
+        run_phase(clock, "pipeline", phase_pipeline, seed=args.seed)
     else:
-        clock.run("train", phase_train, seed=args.seed)
-        clock.run("codec", phase_codec, seed=args.seed)
-        clock.run("serve", phase_serve, seed=args.seed)
+        run_phase(clock, "train", phase_train, seed=args.seed)
+        run_phase(clock, "codec", phase_codec, seed=args.seed)
+        run_phase(clock, "serve", phase_serve, seed=args.seed)
     print(f"compile: {clock.seconds:.2f} s over {clock.programs} programs, "
           f"{clock.cache_hits} from the persistent cache", flush=True)
     print(json.dumps({"ok": True, "device": device}), flush=True)

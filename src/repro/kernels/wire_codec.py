@@ -121,6 +121,7 @@ def encode_fused(x, wire_dtype: str, *, interpret: bool = False):
             jax.ShapeDtypeStruct((rows, nb, 1), jnp.float32, vma=vma),
         ],
         interpret=interpret,
+        name="wire_encode",
     )(x2)
     return q.reshape(lead + (nb, b)), s.reshape(lead + (nb, 1))
 
@@ -146,5 +147,6 @@ def decode_fused(q, scale, out_dtype, *, interpret: bool = False):
         out_shape=jax.ShapeDtypeStruct((rows, nb * b), odt,
                                        vma=jax.typeof(q).vma),
         interpret=interpret,
+        name="wire_decode",
     )(q2, s2)
     return out.reshape(lead + (nb * b,))

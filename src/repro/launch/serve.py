@@ -204,7 +204,9 @@ def _continuous_serve(model, params, args, cache_len):
     lat = stats["qos"]["latency"]
     if lat["p50_ttft_s"] is not None:
         print(f"latency: p50 ttft {lat['p50_ttft_s'] * 1e3:.1f} ms, "
-              f"p99 ttft {lat['p99_ttft_s'] * 1e3:.1f} ms")
+              f"p99 ttft {lat['p99_ttft_s'] * 1e3:.1f} ms; queue wait "
+              f"p50 {lat['p50_queue_s'] * 1e3:.1f} ms, "
+              f"p99 {lat['p99_queue_s'] * 1e3:.1f} ms")
     if args.plan_out:
         doc = {"mode": "continuous", "slots": slots,
                "wire_dtype": args.wire_dtype,

@@ -118,14 +118,16 @@ def _mlp(p, h, cfg: LMConfig, dt):
 
 
 def _ffn(p, x, cfg: LMConfig, dt):
-    """Second half-block: norm + (MoE | MLP) with residual.  -> (x, aux)."""
-    h = apply_norm(x, p["ln2"], cfg.norm)
-    if "moe" in p:
-        out, aux = apply_moe(p["moe"], h, topk=cfg.moe_topk,
-                             cap_factor=cfg.moe_capacity, act=cfg.act,
-                             global_aux=cfg.moe_global_aux)
-        return x + out, aux
-    return x + _mlp(p["mlp"], h, cfg, dt), jnp.float32(0.0)
+    """Second half-block: norm + (MoE | MLP) with residual.  -> (x, aux).
+    Its operations carry the ``mlp`` scope in their HLO metadata."""
+    with jax.named_scope("mlp"):
+        h = apply_norm(x, p["ln2"], cfg.norm)
+        if "moe" in p:
+            out, aux = apply_moe(p["moe"], h, topk=cfg.moe_topk,
+                                 cap_factor=cfg.moe_capacity, act=cfg.act,
+                                 global_aux=cfg.moe_global_aux)
+            return x + out, aux
+        return x + _mlp(p["mlp"], h, cfg, dt), jnp.float32(0.0)
 
 
 def apply_block(p, x, cfg: LMConfig, kind: str, *, positions,
@@ -145,36 +147,37 @@ def apply_block(p, x, cfg: LMConfig, kind: str, *, positions,
         x = x + apply_recurrent(p["rec"], h, dt=dt)
         return _ffn(p, x, cfg, dt)
 
-    # attention kinds
-    h = apply_norm(x, p["ln1"], cfg.norm)
-    q, k, v = _proj_qkv(p["attn"], h, cfg, dt)
-    if use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
-    causal = kind != "enc"
-    window = cfg.window if kind == "local" else 0
-    att = chunked_attention(q, k, v, q_positions=positions,
-                            kv_positions=positions, causal=causal,
-                            window=window, prefix_len=prefix_len)
-    b, s, _, _ = att.shape
-    att = att.reshape(b, s, cfg.n_heads * cfg.hd) @ p["attn"]["o"].astype(dt)
-    if "ob" in p["attn"]:
-        att = att + p["attn"]["ob"].astype(dt)
-    x = x + att
+    # attention kinds: everything up to the MLP carries the scope
+    with jax.named_scope("attention"):
+        h = apply_norm(x, p["ln1"], cfg.norm)
+        q, k, v = _proj_qkv(p["attn"], h, cfg, dt)
+        if use_rope:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
+        causal = kind != "enc"
+        window = cfg.window if kind == "local" else 0
+        att = chunked_attention(q, k, v, q_positions=positions,
+                                kv_positions=positions, causal=causal,
+                                window=window, prefix_len=prefix_len)
+        b, s, _, _ = att.shape
+        att = att.reshape(b, s, cfg.n_heads * cfg.hd) @ p["attn"]["o"].astype(dt)
+        if "ob" in p["attn"]:
+            att = att + p["attn"]["ob"].astype(dt)
+        x = x + att
 
-    if kind == "xattn":
-        assert enc_out is not None
-        h = apply_norm(x, p["lnx"], cfg.norm)
-        bq, sq, _ = h.shape
-        se = enc_out.shape[1]
-        hd = cfg.hd
-        q = (h @ p["cross"]["q"].astype(dt)).reshape(bq, sq, cfg.n_heads, hd)
-        ck = (enc_out @ p["cross"]["k"].astype(dt)).reshape(bq, se, cfg.n_kv, hd)
-        cv = (enc_out @ p["cross"]["v"].astype(dt)).reshape(bq, se, cfg.n_kv, hd)
-        att = chunked_attention(q, ck, cv,
-                                q_positions=jnp.arange(sq),
-                                kv_positions=jnp.arange(se), causal=False)
-        x = x + att.reshape(bq, sq, cfg.n_heads * hd) @ p["cross"]["o"].astype(dt)
+        if kind == "xattn":
+            assert enc_out is not None
+            h = apply_norm(x, p["lnx"], cfg.norm)
+            bq, sq, _ = h.shape
+            se = enc_out.shape[1]
+            hd = cfg.hd
+            q = (h @ p["cross"]["q"].astype(dt)).reshape(bq, sq, cfg.n_heads, hd)
+            ck = (enc_out @ p["cross"]["k"].astype(dt)).reshape(bq, se, cfg.n_kv, hd)
+            cv = (enc_out @ p["cross"]["v"].astype(dt)).reshape(bq, se, cfg.n_kv, hd)
+            att = chunked_attention(q, ck, cv,
+                                    q_positions=jnp.arange(sq),
+                                    kv_positions=jnp.arange(se), causal=False)
+            x = x + att.reshape(bq, sq, cfg.n_heads * hd) @ p["cross"]["o"].astype(dt)
 
     return _ffn(p, x, cfg, dt)
 
@@ -231,39 +234,40 @@ def apply_block_prefill(p, x, cfg: LMConfig, kind: str, *, positions,
         return y, aux, st
 
     # attention kinds
-    h = apply_norm(x, p["ln1"], cfg.norm)
-    q, k, v = _proj_qkv(p["attn"], h, cfg, dt)
-    if use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
-    window = cfg.window if kind == "local" else 0
-    att = chunked_attention(q, k, v, q_positions=positions,
-                            kv_positions=positions, causal=(kind != "enc"),
-                            window=window, prefix_len=prefix_len)
-    state = _kv_into_cache(k, v, cache_len, window=window)
-    b, s, _, _ = att.shape
-    att = att.reshape(b, s, cfg.n_heads * cfg.hd) @ p["attn"]["o"].astype(dt)
-    if "ob" in p["attn"]:
-        att = att + p["attn"]["ob"].astype(dt)
-    x = x + att
+    with jax.named_scope("attention"):
+        h = apply_norm(x, p["ln1"], cfg.norm)
+        q, k, v = _proj_qkv(p["attn"], h, cfg, dt)
+        if use_rope:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
+        window = cfg.window if kind == "local" else 0
+        att = chunked_attention(q, k, v, q_positions=positions,
+                                kv_positions=positions, causal=(kind != "enc"),
+                                window=window, prefix_len=prefix_len)
+        state = _kv_into_cache(k, v, cache_len, window=window)
+        b, s, _, _ = att.shape
+        att = att.reshape(b, s, cfg.n_heads * cfg.hd) @ p["attn"]["o"].astype(dt)
+        if "ob" in p["attn"]:
+            att = att + p["attn"]["ob"].astype(dt)
+        x = x + att
 
-    if kind == "xattn":
-        assert enc_out is not None
-        h = apply_norm(x, p["lnx"], cfg.norm)
-        bq, sq, _ = h.shape
-        se = enc_out.shape[1]
-        hd = cfg.hd
-        q = (h @ p["cross"]["q"].astype(dt)).reshape(bq, sq, cfg.n_heads, hd)
-        ck = (enc_out @ p["cross"]["k"].astype(dt)).reshape(bq, se,
-                                                            cfg.n_kv, hd)
-        cv = (enc_out @ p["cross"]["v"].astype(dt)).reshape(bq, se,
-                                                            cfg.n_kv, hd)
-        att = chunked_attention(q, ck, cv, q_positions=jnp.arange(sq),
-                                kv_positions=jnp.arange(se), causal=False)
-        x = x + att.reshape(bq, sq, cfg.n_heads * hd) \
-            @ p["cross"]["o"].astype(dt)
-        state["ck"] = ck
-        state["cv"] = cv
+        if kind == "xattn":
+            assert enc_out is not None
+            h = apply_norm(x, p["lnx"], cfg.norm)
+            bq, sq, _ = h.shape
+            se = enc_out.shape[1]
+            hd = cfg.hd
+            q = (h @ p["cross"]["q"].astype(dt)).reshape(bq, sq, cfg.n_heads, hd)
+            ck = (enc_out @ p["cross"]["k"].astype(dt)).reshape(bq, se,
+                                                                cfg.n_kv, hd)
+            cv = (enc_out @ p["cross"]["v"].astype(dt)).reshape(bq, se,
+                                                                cfg.n_kv, hd)
+            att = chunked_attention(q, ck, cv, q_positions=jnp.arange(sq),
+                                    kv_positions=jnp.arange(se), causal=False)
+            x = x + att.reshape(bq, sq, cfg.n_heads * hd) \
+                @ p["cross"]["o"].astype(dt)
+            state["ck"] = ck
+            state["cv"] = cv
 
     y, aux = _ffn(p, x, cfg, dt)
     return y, aux, state
@@ -325,50 +329,51 @@ def apply_block_decode(p, x, state, cfg: LMConfig, kind: str, *, position,
         x, _ = _ffn(p, x, cfg, dt)
         return x, s_new
 
-    h = apply_norm(x, p["ln1"], cfg.norm)
-    q, k, v = _proj_qkv(p["attn"], h, cfg, dt)
-    pos_arr = jnp.full((1,), position)
-    if use_rope:
-        q = apply_rope(q, pos_arr, cfg.rope_theta)
-        k = apply_rope(k, pos_arr, cfg.rope_theta)
-    if kind == "local":
-        w = state["k"].shape[1]
-        idx = position % w
-    else:
-        idx = position
-    k_cache = jax.lax.dynamic_update_slice_in_dim(state["k"], k.astype(state["k"].dtype), idx, 1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(state["v"], v.astype(state["v"].dtype), idx, 1)
-    if kind == "local":
-        # ring buffer: all entries valid once warm; mask handled by window
-        att = decode_attention(q, k_cache, v_cache,
-                               position=jnp.minimum(position, k_cache.shape[1] - 1),
-                               window=0)
-    else:
-        att = decode_attention(q, k_cache, v_cache, position=position)
-    b = x.shape[0]
-    att = att.reshape(b, 1, cfg.n_heads * cfg.hd) @ p["attn"]["o"].astype(dt)
-    if "ob" in p["attn"]:
-        att = att + p["attn"]["ob"].astype(dt)
-    x = x + att
-    new_state = {"k": k_cache, "v": v_cache}
-
-    if kind == "xattn":
-        h = apply_norm(x, p["lnx"], cfg.norm)
-        hd = cfg.hd
-        q = (h @ p["cross"]["q"].astype(dt)).reshape(b, 1, cfg.n_heads, hd)
-        if "ck" in state:          # precomputed at prefill
-            ck, cv = state["ck"].astype(dt), state["cv"].astype(dt)
-            new_state["ck"] = state["ck"]
-            new_state["cv"] = state["cv"]
+    with jax.named_scope("attention"):
+        h = apply_norm(x, p["ln1"], cfg.norm)
+        q, k, v = _proj_qkv(p["attn"], h, cfg, dt)
+        pos_arr = jnp.full((1,), position)
+        if use_rope:
+            q = apply_rope(q, pos_arr, cfg.rope_theta)
+            k = apply_rope(k, pos_arr, cfg.rope_theta)
+        if kind == "local":
+            w = state["k"].shape[1]
+            idx = position % w
         else:
-            assert enc_out is not None
-            se = enc_out.shape[1]
-            ck = (enc_out @ p["cross"]["k"].astype(dt)).reshape(
-                b, se, cfg.n_kv, hd)
-            cv = (enc_out @ p["cross"]["v"].astype(dt)).reshape(
-                b, se, cfg.n_kv, hd)
-        att = decode_attention(q, ck, cv, position=ck.shape[1] - 1)
-        x = x + att.reshape(b, 1, cfg.n_heads * hd) @ p["cross"]["o"].astype(dt)
+            idx = position
+        k_cache = jax.lax.dynamic_update_slice_in_dim(state["k"], k.astype(state["k"].dtype), idx, 1)
+        v_cache = jax.lax.dynamic_update_slice_in_dim(state["v"], v.astype(state["v"].dtype), idx, 1)
+        if kind == "local":
+            # ring buffer: all entries valid once warm; mask handled by window
+            att = decode_attention(q, k_cache, v_cache,
+                                   position=jnp.minimum(position, k_cache.shape[1] - 1),
+                                   window=0)
+        else:
+            att = decode_attention(q, k_cache, v_cache, position=position)
+        b = x.shape[0]
+        att = att.reshape(b, 1, cfg.n_heads * cfg.hd) @ p["attn"]["o"].astype(dt)
+        if "ob" in p["attn"]:
+            att = att + p["attn"]["ob"].astype(dt)
+        x = x + att
+        new_state = {"k": k_cache, "v": v_cache}
+
+        if kind == "xattn":
+            h = apply_norm(x, p["lnx"], cfg.norm)
+            hd = cfg.hd
+            q = (h @ p["cross"]["q"].astype(dt)).reshape(b, 1, cfg.n_heads, hd)
+            if "ck" in state:          # precomputed at prefill
+                ck, cv = state["ck"].astype(dt), state["cv"].astype(dt)
+                new_state["ck"] = state["ck"]
+                new_state["cv"] = state["cv"]
+            else:
+                assert enc_out is not None
+                se = enc_out.shape[1]
+                ck = (enc_out @ p["cross"]["k"].astype(dt)).reshape(
+                    b, se, cfg.n_kv, hd)
+                cv = (enc_out @ p["cross"]["v"].astype(dt)).reshape(
+                    b, se, cfg.n_kv, hd)
+            att = decode_attention(q, ck, cv, position=ck.shape[1] - 1)
+            x = x + att.reshape(b, 1, cfg.n_heads * hd) @ p["cross"]["o"].astype(dt)
 
     x, _ = _ffn(p, x, cfg, dt)
     return x, new_state

@@ -114,10 +114,11 @@ class LM:
 
     def _embed(self, params, tokens, dt):
         cfg = self.cfg
-        x = params["embed"].astype(dt)[tokens]
-        if cfg.embed_scale:
-            x = x * jnp.asarray(math.sqrt(cfg.d_model), dt)
-        return x
+        with jax.named_scope("embed"):
+            x = params["embed"].astype(dt)[tokens]
+            if cfg.embed_scale:
+                x = x * jnp.asarray(math.sqrt(cfg.d_model), dt)
+            return x
 
     def _run_blocks(self, params, x, *, positions, prefix_len=0, enc_out=None):
         cfg = self.cfg
@@ -201,6 +202,13 @@ class LM:
             return params["embed"].astype(dt).T
         return params["head"].astype(dt)
 
+    def head_loss(self, params, h, labels):
+        """Final norm, head and cross entropy of the last hidden states
+        ``h`` [B, S, D]; its operations carry the ``head_loss`` scope."""
+        with jax.named_scope("head_loss"):
+            h = apply_norm(h, params["final_norm"], self.cfg.norm)
+            return self.xent(params, h, labels)
+
     def xent(self, params, h, labels, chunk: int = 512):
         """Chunked softmax cross entropy.  h [B,S,D], labels [B,S] (-1 pad)."""
         cfg = self.cfg
@@ -262,10 +270,9 @@ class LM:
         positions = jnp.arange(x.shape[1])
         x, aux = self._run_blocks(params, x, positions=positions,
                                   prefix_len=prefix_len, enc_out=enc_out)
-        x = apply_norm(x, params["final_norm"], cfg.norm)
         if labels is None:
-            return x, {"aux": aux}
-        loss = self.xent(params, x, labels)
+            return apply_norm(x, params["final_norm"], cfg.norm), {"aux": aux}
+        loss = self.head_loss(params, x, labels)
         total = loss + 0.01 * aux
         return total, {"xent": loss, "aux": aux}
 
@@ -340,9 +347,10 @@ class LM:
                     lambda a, t: a.astype(t.dtype), st, template[i]))
             cache = tuple(sts)
 
-        x = apply_norm(x, params["final_norm"], cfg.norm)
-        logits = _softcap(x[:, -1] @ self._head_w(params, dt),
-                          cfg.logit_softcap)
+        with jax.named_scope("head_loss"):
+            x = apply_norm(x, params["final_norm"], cfg.norm)
+            logits = _softcap(x[:, -1] @ self._head_w(params, dt),
+                              cfg.logit_softcap)
         serve_state = {"cache": cache,
                        "position": jnp.asarray(s, jnp.int32)}
         return logits[:, :cfg.vocab].astype(jnp.float32), serve_state
@@ -408,7 +416,8 @@ class LM:
                 new_states.append(st)
             new_cache = tuple(new_states)
 
-        x = apply_norm(x, params["final_norm"], cfg.norm)
-        logits = _softcap(x[:, 0] @ self._head_w(params, dt),
-                          cfg.logit_softcap)
+        with jax.named_scope("head_loss"):
+            x = apply_norm(x, params["final_norm"], cfg.norm)
+            logits = _softcap(x[:, 0] @ self._head_w(params, dt),
+                              cfg.logit_softcap)
         return logits[:, :cfg.vocab].astype(jnp.float32), new_cache

@@ -62,7 +62,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.blocks import apply_block
-from repro.models.common import apply_norm
 from repro.parallel import compat, wire
 from repro.parallel.compat import PartitionSpec as P
 from repro.parallel.context import ParallelCtx, use_ctx
@@ -367,13 +366,15 @@ def _tick_loop(spec, stage, k, xs_full, enc_full, state0, aux0, run_stage,
         uncoded pipeline), or the quantized wire round trip whose
         custom_vjp codes the transposed backward hop the same way —
         top-k + error feedback on that backward hop when ``ef_t`` rides
-        along."""
-        if not coded:
-            return jax.lax.ppermute(y, spec.axis, perm)
-        if ef_t is not None:
-            return wire.coded_ppermute_ef(spec.wire_dtype, spec.axis,
-                                          perm, y, ef_t)
-        return wire.coded_ppermute(base_wire, spec.axis, perm, y)
+        along.  Its operations, the codec's and the backward hop's
+        included, carry the ``pipeline.hop`` scope."""
+        with jax.named_scope("pipeline.hop"):
+            if not coded:
+                return jax.lax.ppermute(y, spec.axis, perm)
+            if ef_t is not None:
+                return wire.coded_ppermute_ef(spec.wire_dtype, spec.axis,
+                                              perm, y, ef_t)
+            return wire.coded_ppermute(base_wire, spec.axis, perm, y)
 
     def tick(carry, xt):
         state, aux_acc = carry
@@ -576,8 +577,7 @@ def make_pipelined_loss(model, spec: PipelineSpec, mesh=None):
                                        prefix_len=prefix_len,
                                        enc_outs=enc_outs, wire_ef=wire_ef)
             h = out.reshape(b + pad_rows, seq, x.shape[-1])[:b]
-            h = apply_norm(h, params["final_norm"], cfg.norm)
-            loss = model.xent(params, h, labels)
+            loss = model.head_loss(params, h, labels)
             total = loss + 0.01 * aux
             return total, {"xent": loss, "aux": aux}
 

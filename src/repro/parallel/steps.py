@@ -61,16 +61,17 @@ def make_lm_train_step(model: LM, opt: Optimizer, *, microbatches: int = 1,
             new_state["wire_ef"] = new_ef
         else:
             (loss, mets), grads = vg(params, batch)
-        if compress:
-            from repro.training.compress import (compress_grads,
-                                                 decompress_grads)
-            qtree, new_efb = compress_grads(
-                jax.tree.map(lambda g: g.astype(jnp.float32), grads),
-                state_tree["error_fb"])
-            grads = decompress_grads(qtree)
-            new_state["error_fb"] = new_efb
-        new_params, new_opt = opt.update(grads, state_tree["opt_state"],
-                                         params, state_tree["step"])
+        with jax.named_scope("optimizer"):
+            if compress:
+                from repro.training.compress import (compress_grads,
+                                                     decompress_grads)
+                qtree, new_efb = compress_grads(
+                    jax.tree.map(lambda g: g.astype(jnp.float32), grads),
+                    state_tree["error_fb"])
+                grads = decompress_grads(qtree)
+                new_state["error_fb"] = new_efb
+            new_params, new_opt = opt.update(grads, state_tree["opt_state"],
+                                             params, state_tree["step"])
         mets = dict(mets)
         mets["loss"] = loss
         new_state.update(params=new_params, opt_state=new_opt,

@@ -151,6 +151,13 @@ class RequestTimeline:
     tokens: int = 0
 
     @property
+    def queue_s(self) -> float | None:
+        """Submit -> admit: the wait for a free slot."""
+        if self.admit_t is None:
+            return None
+        return self.admit_t - self.submit_t
+
+    @property
     def ttft_s(self) -> float | None:
         """Submit -> first emitted token (queue wait + prefill + the
         first decode)."""
@@ -173,7 +180,8 @@ class ServingQoS:
 
     The engine stamps submit/admit/first-token/done per request; the
     snapshot derives p50/p99 TTFT and per-token latency (nearest-rank,
-    over COMPLETED requests) next to the admission counters.  ``clock``
+    over COMPLETED requests) and the queue wait for a slot (submit ->
+    admit, over ADMITTED requests) next to the admission counters.  ``clock``
     is injectable so tests can drive a scripted clock and pin exact
     percentile values.
     """
@@ -222,11 +230,15 @@ class ServingQoS:
         done = [r for r in self.requests.values() if r.done_t is not None]
         ttft = [r.ttft_s for r in done if r.ttft_s is not None]
         per_tok = [r.per_token_s for r in done if r.per_token_s is not None]
+        queue = [r.queue_s for r in self.requests.values()
+                 if r.queue_s is not None]
         return {
             "p50_ttft_s": percentile(ttft, 50),
             "p99_ttft_s": percentile(ttft, 99),
             "p50_tok_s": percentile(per_tok, 50),
             "p99_tok_s": percentile(per_tok, 99),
+            "p50_queue_s": percentile(queue, 50),
+            "p99_queue_s": percentile(queue, 99),
         }
 
     def snapshot(self) -> dict:

@@ -30,13 +30,27 @@ run-to-completion convoy baseline bills ``batch * max_gen`` per group —
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.runtime.qos import ServingQoS
 from repro.serving import kv
 from repro.serving.scheduler import Request, Scheduler
+
+
+def _arena(fn):
+    """``fn`` with its operations under the ``arena`` scope: the slot
+    arena's row copies, told apart from the model in a device trace.  The
+    jitted program keeps ``fn``'s name."""
+    @functools.wraps(fn)
+    def scoped(*args):
+        with jax.named_scope("arena"):
+            return fn(*args)
+    return scoped
 
 
 def _check_servable(cfg):
@@ -119,14 +133,14 @@ class ServingEngine:
         self.decode_steps = 0
         self.prefill_chunks = 0
         self.engine_units = 0                     # modeled lane-tokens
-        self.occupancy_trace: list[int] = []
+        self.lane_steps = 0                       # active lanes, summed
 
         self._step = jax.jit(self._build_step())
         self._prefill = jax.jit(self._prefill_bucket)
-        self._take_row = jax.jit(
-            lambda tree, i: kv.take_slot(tree, self.axes, i))
-        self._put_row = jax.jit(
-            lambda tree, row, s: kv.put_slot(tree, self.axes, row, s))
+        self._take_row = jax.jit(_arena(
+            lambda tree, i: kv.take_slot(tree, self.axes, i)))
+        self._put_row = jax.jit(_arena(
+            lambda tree, row, s: kv.put_slot(tree, self.axes, row, s)))
 
     # -- jitted programs -----------------------------------------------------
 
@@ -137,7 +151,8 @@ class ServingEngine:
         def step(params, cache, positions, active, tokens, req_seed,
                  tok_idx):
             def lane(row, pos, tok, rs, ti):
-                cache_b = kv.expand_slot(row, axes)
+                with jax.named_scope("arena"):
+                    cache_b = kv.expand_slot(row, axes)
                 logits, new_cache = model.decode_step(
                     params, tok[None, None], cache_b, pos)
                 logits = logits[0]
@@ -148,14 +163,16 @@ class ServingEngine:
                         key, logits / temperature, axis=-1)
                 else:
                     nxt = jnp.argmax(logits, axis=-1)
-                return (kv.squeeze_slot(new_cache, axes),
-                        nxt.astype(jnp.int32))
+                with jax.named_scope("arena"):
+                    row = kv.squeeze_slot(new_cache, axes)
+                return row, nxt.astype(jnp.int32)
 
             new_cache, nxt = jax.vmap(
                 lane, in_axes=(axes, 0, 0, 0, 0),
                 out_axes=(axes, 0))(cache, positions, tokens, req_seed,
                                     tok_idx)
-            new_cache = kv.where_slots(active, new_cache, cache, axes)
+            with jax.named_scope("arena"):
+                new_cache = kv.where_slots(active, new_cache, cache, axes)
             nxt = jnp.where(active, nxt, tokens)
             return new_cache, nxt
 
@@ -207,10 +224,17 @@ class ServingEngine:
             self.params, self.cache, jnp.asarray(self.positions),
             jnp.asarray(self.active), jnp.asarray(self.tokens),
             jnp.asarray(self.req_seed), jnp.asarray(self.tok_idx))
-        nxt = np.asarray(nxt)
+        with TraceAnnotation("engine.sync"):
+            nxt = np.asarray(nxt)
         self.decode_steps += 1
         self.engine_units += self.slots
-        self.occupancy_trace.append(int(self.active.sum()))
+        self.lane_steps += int(self.active.sum())
+        with TraceAnnotation("engine.emit"):
+            self._emit(nxt)
+
+    def _emit(self, nxt: np.ndarray) -> None:
+        """Hand each active lane's token to its request; free the slots
+        of requests that reached their length."""
         finished = []
         for slot, req in self._tenant.items():
             if not self.active[slot]:
@@ -232,14 +256,23 @@ class ServingEngine:
 
     def step_once(self) -> bool:
         """One engine iteration: at most one prefill chunk, then one
-        decode step.  Returns False when fully idle."""
-        chunk = self.scheduler.next_chunk(len(self.freelist))
-        if chunk:
-            self._admit_chunk(chunk)
-        if self.active.any():
-            self._decode_once()
-            return True
-        return bool(chunk)
+        decode step.  Returns False when fully idle.
+
+        The host spans ``engine.step`` (all of it), ``engine.schedule``,
+        ``engine.admit`` and ``engine.decode`` (with ``engine.sync`` and
+        ``engine.emit`` inside) land in a profiler trace on the device's
+        clock; with no profiler running each is one cheap TraceMe."""
+        with TraceAnnotation("engine.step"):
+            with TraceAnnotation("engine.schedule"):
+                chunk = self.scheduler.next_chunk(len(self.freelist))
+            if chunk:
+                with TraceAnnotation("engine.admit"):
+                    self._admit_chunk(chunk)
+            if self.active.any():
+                with TraceAnnotation("engine.decode"):
+                    self._decode_once()
+                return True
+            return bool(chunk)
 
     def run(self, requests=None, max_steps: int | None = None) -> dict:
         """Drain: submit ``requests`` (optional), iterate until idle.
@@ -256,13 +289,13 @@ class ServingEngine:
         return dict(self.done)
 
     def stats(self) -> dict:
-        occ = self.occupancy_trace
         return {
             "decode_steps": self.decode_steps,
             "prefill_chunks": self.prefill_chunks,
             "engine_units": self.engine_units,
-            "occupancy_mean": (float(np.mean(occ)) if occ else 0.0),
-            "occupancy_trace_sum": int(np.sum(occ)) if occ else 0,
+            "lane_steps": self.lane_steps,
+            "occupancy_mean": (self.lane_steps / self.decode_steps
+                               if self.decode_steps else 0.0),
             "qos": self.qos.snapshot(),
         }
 

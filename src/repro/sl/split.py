@@ -105,7 +105,6 @@ def lm_split(model, l: int) -> SplitSpec:
 
     def bs_loss(bs_params, acts, labels):
         from repro.models.blocks import apply_block
-        from repro.models.common import apply_norm
         positions = jnp.arange(acts.shape[1])
 
         def body(carry, layer_params):
@@ -114,10 +113,9 @@ def lm_split(model, l: int) -> SplitSpec:
             return y, None
 
         x, _ = jax.lax.scan(body, acts, bs_params["blocks"])
-        x = apply_norm(x, bs_params["final_norm"], cfg.norm)
         if cfg.tie_embeddings:
             raise ValueError("tied embeddings cannot be split at the head")
-        loss = model.xent(bs_params, x, labels)
+        loss = model.head_loss(bs_params, x, labels)
         return loss, {"xent": loss}
 
     return SplitSpec(ue_fwd, bs_loss, split_params, merge_params)

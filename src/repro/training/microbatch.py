@@ -40,7 +40,8 @@ def microbatched_value_and_grad(loss_fn, k: int):
         def body(carry, mb):
             (loss, mets), grads = vg(params, mb)
             acc_loss, acc_mets, acc_grads = carry
-            acc = jax.tree.map(jnp.add, acc_grads, grads)
+            with jax.named_scope("grad_accum"):
+                acc = jax.tree.map(jnp.add, acc_grads, grads)
             mets_sum = jax.tree.map(jnp.add, acc_mets, mets)
             return (acc_loss + loss, mets_sum, acc), None
 

@@ -6,6 +6,7 @@ The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and every test worker
 imports this file.  The fixture skips where no v5e can be described.
 """
+import importlib.util
 import os
 
 import jax
@@ -57,3 +58,39 @@ def test_fused_codec_compiles_for_v5e(one_chip, lead, dtype, wire_dtype):
             for a in (q, s))
     dec = jax.jit(lambda q, s: decode_fused(q, s, dtype)).lower(q, s).compile()
     assert "tpu_custom_call" in dec.as_text()
+
+
+@pytest.mark.parametrize("wire_dtype", ["int8", "fp8"])
+def test_codec_kernels_keep_their_names_for_the_benchmark(one_chip,
+                                                          monkeypatch,
+                                                          wire_dtype):
+    """The codec's custom calls are named ``wire_encode``/``wire_decode``,
+    and the benchmark's ``wire_codec_roofline`` still tells them apart
+    by their operand and result types, as a trace names them."""
+    bench = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                         "bench")
+    monkeypatch.syspath_prepend(bench)
+    from harness import trace
+    spec = importlib.util.spec_from_file_location(
+        "wire_codec_roofline",
+        os.path.join(bench, "metrics", "wire_codec_roofline.py"))
+    roofline = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(roofline)
+
+    x = jax.ShapeDtypeStruct((2, 4096, D_MODEL), jnp.bfloat16,
+                             sharding=one_chip)
+    enc = jax.jit(lambda x: encode_fused(x, wire_dtype)).lower(x).compile()
+    q, s = jax.eval_shape(lambda x: encode_fused(x, wire_dtype), x)
+    q, s = (jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in (q, s))
+    dec = jax.jit(lambda q, s: decode_fused(q, s, jnp.bfloat16)).lower(
+        q, s).compile()
+    for compiled, name, match in ((enc, "wire_encode", roofline.is_encode),
+                                  (dec, "wire_decode", roofline.is_decode)):
+        calls = [trace.short_name(line.strip())
+                 for line in compiled.as_text().splitlines()
+                 if "custom_call_target=\"tpu_custom_call\"" in line]
+        assert calls and all(c.startswith(name + ".") for c in calls), calls
+        assert all(match(c) for c in calls), calls
+        assert not any(roofline.is_encode(c) and roofline.is_decode(c)
+                       for c in calls)

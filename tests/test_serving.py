@@ -282,11 +282,12 @@ def test_percentile_nearest_rank():
 def test_serving_qos_scripted_clock():
     t = {"now": 0.0}
     q = ServingQoS(clock=lambda: t["now"])
-    for rid, (ttft, per_tok, n) in enumerate([(1.0, 0.5, 3),
-                                              (2.0, 0.25, 5),
-                                              (4.0, 1.0, 2)]):
+    for rid, (wait, ttft, per_tok, n) in enumerate([(0.5, 1.0, 0.5, 3),
+                                                    (1.5, 2.0, 0.25, 5),
+                                                    (0.25, 4.0, 1.0, 2)]):
         t["now"] = 0.0
         q.record_submit(rid)
+        t["now"] = wait
         q.record_admit(rid, step=0)
         t["now"] = ttft
         q.record_token(rid, step=1)
@@ -304,6 +305,7 @@ def test_serving_qos_scripted_clock():
     lat = snap["latency"]
     assert lat["p50_ttft_s"] == 2.0 and lat["p99_ttft_s"] == 4.0
     assert lat["p50_tok_s"] == 0.5 and lat["p99_tok_s"] == 1.0
+    assert lat["p50_queue_s"] == 0.5 and lat["p99_queue_s"] == 1.5
     with pytest.raises(ValueError):
         q.record_submit(99)                  # duplicate submit
     with pytest.raises(KeyError):
