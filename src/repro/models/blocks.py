@@ -130,6 +130,16 @@ def _ffn(p, x, cfg: LMConfig, dt):
         return x + _mlp(p["mlp"], h, cfg, dt), jnp.float32(0.0)
 
 
+def _ffn_decode(p, x, cfg: LMConfig, dt, per_lane: bool):
+    """The decode step's second half-block -> x.  With ``per_lane`` (a
+    [B] position: each lane is its own request) a MoE dispatches every
+    lane alone, as a batch of one, so a lane's expert capacity, and
+    whether its token is dropped, never depends on the other lanes."""
+    if per_lane and "moe" in p:
+        return jax.vmap(lambda xl: _ffn(p, xl[None], cfg, dt)[0][0])(x)
+    return _ffn(p, x, cfg, dt)[0]
+
+
 def apply_block(p, x, cfg: LMConfig, kind: str, *, positions,
                 prefix_len: int = 0, enc_out=None, use_rope: bool = True):
     """x: [B, S, D] -> ([B, S, D], aux_loss)."""
@@ -307,62 +317,133 @@ def init_block_state(cfg: LMConfig, kind: str, batch: int, cache_len: int,
     raise ValueError(kind)
 
 
+def _row(a, layer):
+    """This block's rows of a cache leaf: the leaf itself, or row
+    ``layer`` of a scan-stacked [L, ...] leaf."""
+    return a if layer is None else jax.lax.dynamic_index_in_dim(
+        a, layer, 0, keepdims=False)
+
+
+def _write_token(buf, new, idx, layer):
+    """Write one token per lane, ``new`` [B, 1, ...], at index ``idx`` of
+    ``buf`` [B, S, ...] (or of its row ``layer`` when ``buf`` is
+    [L, B, S, ...]), and nothing else of ``buf``, so a donated cache is
+    updated in place.  A scalar ``idx`` (every lane at one position) is
+    one dynamic-update-slice; a [B] ``idx`` is one per lane.
+
+    Every lane writes, an inactive one too, at the index of its held
+    position.  That is safe because nothing reads a lane's cache while
+    it is inactive, and the lane's next active step, at that same held
+    position, writes the same index before its attention reads it.
+    Holding the token instead (a select against the token there, a
+    scatter that drops the lane) or writing all lanes with one scatter
+    makes the TPU compiler copy the whole cache into another layout and
+    back around the step."""
+    new = new.astype(buf.dtype)
+    lead = () if layer is None else (layer,)
+    tail = (0,) * (new.ndim - 2)
+    if idx.ndim == 0:
+        upd = new.reshape((1,) * len(lead) + new.shape)
+        return jax.lax.dynamic_update_slice(buf, upd, lead + (0, idx) + tail)
+    for b in range(new.shape[0]):
+        upd = new[b:b + 1].reshape((1,) * len(lead) + (1,) + new.shape[1:])
+        buf = jax.lax.dynamic_update_slice(buf, upd, lead + (b, idx[b]) + tail)
+    return buf
+
+
+def _hold_state(state, old, new, active, layer):
+    """A recurrent block's new state, ``old`` (its rows of ``state``)
+    held where ``active`` is False, put back into row ``layer`` of a
+    scan-stacked state."""
+    if active is not None:
+        new = jax.tree.map(
+            lambda n, o: jnp.where(
+                active.reshape((-1,) + (1,) * (n.ndim - 1)), n, o),
+            new, old)
+    if layer is None:
+        return new
+    return jax.tree.map(lambda a, n: a.at[layer].set(n.astype(a.dtype)),
+                        state, new)
+
+
 def apply_block_decode(p, x, state, cfg: LMConfig, kind: str, *, position,
-                       enc_out=None, use_rope: bool = True):
-    """x: [B, 1, D], state per kind -> ([B, 1, D], new_state)."""
+                       enc_out=None, use_rope: bool = True, active=None,
+                       layer=None):
+    """x: [B, 1, D], state per kind -> ([B, 1, D], new_state).
+
+    ``position`` is a scalar or a [B] vector, one position per lane.
+    A [B] vector also makes each lane's MoE dispatch its own
+    (``_ffn_decode``).  ``active`` ([B] bool, optional): lanes where it
+    is False keep their recurrent state; every lane writes its K/V token,
+    under the condition ``_write_token`` states.  With ``layer`` (a
+    traced index) ``state`` is the whole scan-stacked cache ([L, B, ...]
+    leaves) and the block reads and writes its row ``layer`` of it.  The
+    state's write carries the ``arena`` scope, the name the serving
+    engine's slot arena is measured by.
+    """
     dt = x.dtype
+    b = x.shape[0]
     if kind == "rwkv":
+        st = jax.tree.map(lambda a: _row(a, layer), state)
         h = apply_norm(x, p["ln1"], cfg.norm)
         t_out, (lx, s_new) = apply_rwkv_time(
             p["time"], h, cfg.rwkv_head_dim,
-            shift_in=state["shift_t"], state_in=state["s"], dt=dt)
+            shift_in=st["shift_t"], state_in=st["s"], dt=dt)
         x = x + t_out
         h = apply_norm(x, p["ln2"], cfg.norm)
         c_out, lc = apply_rwkv_channel(p["channel"], h,
-                                       shift_in=state["shift_c"], dt=dt)
-        return x + c_out, {"s": s_new, "shift_t": lx, "shift_c": lc}
+                                       shift_in=st["shift_c"], dt=dt)
+        with jax.named_scope("arena"):
+            new_state = _hold_state(
+                state, st, {"s": s_new, "shift_t": lx, "shift_c": lc},
+                active, layer)
+        return x + c_out, new_state
 
     if kind == "rglru":
+        st = jax.tree.map(lambda a: _row(a, layer), state)
         h = apply_norm(x, p["ln1"], cfg.norm)
-        out, s_new = apply_recurrent_decode(p["rec"], h, state, dt=dt)
+        out, s_new = apply_recurrent_decode(p["rec"], h, st, dt=dt)
         x = x + out
-        x, _ = _ffn(p, x, cfg, dt)
-        return x, s_new
+        x = _ffn_decode(p, x, cfg, dt, jnp.ndim(position) == 1)
+        with jax.named_scope("arena"):
+            new_state = _hold_state(state, st, s_new, active, layer)
+        return x, new_state
 
+    pos = jnp.broadcast_to(jnp.asarray(position, jnp.int32), (b,))
     with jax.named_scope("attention"):
         h = apply_norm(x, p["ln1"], cfg.norm)
         q, k, v = _proj_qkv(p["attn"], h, cfg, dt)
-        pos_arr = jnp.full((1,), position)
         if use_rope:
-            q = apply_rope(q, pos_arr, cfg.rope_theta)
-            k = apply_rope(k, pos_arr, cfg.rope_theta)
+            q = apply_rope(q, pos[:, None], cfg.rope_theta)
+            k = apply_rope(k, pos[:, None], cfg.rope_theta)
+        w = state["k"].shape[-3]
+        idx = jnp.asarray(position, jnp.int32)    # scalar, or one per lane
         if kind == "local":
-            w = state["k"].shape[1]
-            idx = position % w
-        else:
-            idx = position
-        k_cache = jax.lax.dynamic_update_slice_in_dim(state["k"], k.astype(state["k"].dtype), idx, 1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(state["v"], v.astype(state["v"].dtype), idx, 1)
+            idx = idx % w
+        with jax.named_scope("arena"):
+            new_state = {"k": _write_token(state["k"], k, idx, layer),
+                         "v": _write_token(state["v"], v, idx, layer)}
+        k_cache = _row(new_state["k"], layer)
+        v_cache = _row(new_state["v"], layer)
         if kind == "local":
             # ring buffer: all entries valid once warm; mask handled by window
             att = decode_attention(q, k_cache, v_cache,
-                                   position=jnp.minimum(position, k_cache.shape[1] - 1),
+                                   position=jnp.minimum(pos, w - 1),
                                    window=0)
         else:
-            att = decode_attention(q, k_cache, v_cache, position=position)
-        b = x.shape[0]
+            att = decode_attention(q, k_cache, v_cache, position=pos)
         att = att.reshape(b, 1, cfg.n_heads * cfg.hd) @ p["attn"]["o"].astype(dt)
         if "ob" in p["attn"]:
             att = att + p["attn"]["ob"].astype(dt)
         x = x + att
-        new_state = {"k": k_cache, "v": v_cache}
 
         if kind == "xattn":
             h = apply_norm(x, p["lnx"], cfg.norm)
             hd = cfg.hd
             q = (h @ p["cross"]["q"].astype(dt)).reshape(b, 1, cfg.n_heads, hd)
             if "ck" in state:          # precomputed at prefill
-                ck, cv = state["ck"].astype(dt), state["cv"].astype(dt)
+                ck = _row(state["ck"], layer).astype(dt)
+                cv = _row(state["cv"], layer).astype(dt)
                 new_state["ck"] = state["ck"]
                 new_state["cv"] = state["cv"]
             else:
@@ -375,5 +456,5 @@ def apply_block_decode(p, x, state, cfg: LMConfig, kind: str, *, position,
             att = decode_attention(q, ck, cv, position=ck.shape[1] - 1)
             x = x + att.reshape(b, 1, cfg.n_heads * hd) @ p["cross"]["o"].astype(dt)
 
-    x, _ = _ffn(p, x, cfg, dt)
+    x = _ffn_decode(p, x, cfg, dt, jnp.ndim(position) == 1)
     return x, new_state

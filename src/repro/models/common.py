@@ -85,7 +85,8 @@ NEG_INF = -1e30
 
 @jax.checkpoint
 def _attend_block(q, k, v, mask):
-    """q [B,hq,G,dh] (G=q block), k/v [B,hkv,S,dh], mask [G,S] bool.
+    """q [B,hq,G,dh] (G=q block), k/v [B,hkv,S,dh], mask [G,S] or
+    [B,G,S] bool.
 
     ``jax.checkpoint`` = flash-attention-style backward: the [G,S] logits /
     probabilities are recomputed in the backward pass instead of being saved
@@ -97,7 +98,8 @@ def _attend_block(q, k, v, mask):
     qg = q.reshape(b, hkv, rep, g, dh)
     logits = jnp.einsum("bkrgd,bksd->bkrgs", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) / math.sqrt(dh)
-    logits = jnp.where(mask[None, None, None], logits, NEG_INF)
+    mask = mask[None, None, None] if mask.ndim == 2 else mask[:, None, None]
+    logits = jnp.where(mask, logits, NEG_INF)
     p = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkrgs,bksd->bkrgd", p, v.astype(jnp.float32))
     return out.reshape(b, hq, g, dh).astype(q.dtype)
@@ -158,17 +160,19 @@ def chunked_attention(q, k, v, *, q_positions, kv_positions, causal: bool,
 def decode_attention(q, k_cache, v_cache, *, position, window: int = 0):
     """Single-token decode: q [B,1,Hq,dh], caches [B,S,Hkv,dh].
 
-    ``position`` is the index of the token being generated; cache entries at
-    kv index >= position (or outside the local window) are masked.
+    ``position`` (a scalar, or [B]: one per lane) is the index of the
+    token being generated; cache entries at kv index > position (or
+    outside the local window) are masked.
     """
-    b, _, hq, dh = q.shape
+    b = q.shape[0]
     s = k_cache.shape[1]
-    kv_pos = jnp.arange(s)
-    mask = kv_pos <= position
+    kv_pos = jnp.arange(s)[None, :]
+    pos = jnp.broadcast_to(jnp.asarray(position), (b,))[:, None]
+    mask = kv_pos <= pos
     if window > 0:
-        mask = mask & (kv_pos > position - window)
+        mask = mask & (kv_pos > pos - window)
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k_cache, 1, 2)
     vt = jnp.swapaxes(v_cache, 1, 2)
-    out = _attend_block(qt, kt, vt, mask[None, :])
+    out = _attend_block(qt, kt, vt, mask[:, None, :])
     return jnp.swapaxes(out, 1, 2)

@@ -385,10 +385,15 @@ class LM:
             new.append(st)
         return tuple(new)
 
-    def decode_step(self, params, tokens, cache, position, enc_out=None):
+    def decode_step(self, params, tokens, cache, position, enc_out=None,
+                    active=None):
         """One serving step: tokens [B, 1] -> (logits [B, V], new cache).
 
-        ``position`` is a scalar int (same position across the batch).
+        ``position`` is a scalar (every lane at one position) or a [B]
+        vector (one per lane).  ``active`` ([B] bool, optional): lanes
+        where it is False keep their recurrent state.  The cache is
+        written one token per lane per layer and nowhere else, so a
+        donated cache is updated in place.
         """
         cfg = self.cfg
         dt = jnp.dtype(cfg.dtype)
@@ -396,23 +401,16 @@ class LM:
         x = self._embed(params, tokens, dt)
 
         if cfg.homogeneous and not isinstance(params["blocks"], tuple):
-            kind = kinds[0]
-
-            def body(carry, inp):
-                layer_params, st = inp
-                y, st_new = apply_block_decode(
-                    layer_params, carry, st, cfg, kind, position=position,
-                    enc_out=enc_out, use_rope=(kind != "rwkv"))
-                return y, st_new
-
-            x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache))
+            x, new_cache = decode_stack(params["blocks"], x, cache, cfg,
+                                        kinds[0], position=position,
+                                        active=active, enc_out=enc_out)
         else:
             new_states = []
             for i, kind in enumerate(kinds):
                 x, st = apply_block_decode(
                     params["blocks"][i], x, cache[i], cfg, kind,
                     position=position, enc_out=enc_out,
-                    use_rope=(kind not in ("rwkv",)))
+                    use_rope=(kind not in ("rwkv",)), active=active)
                 new_states.append(st)
             new_cache = tuple(new_states)
 
@@ -421,3 +419,26 @@ class LM:
             logits = _softcap(x[:, 0] @ self._head_w(params, dt),
                               cfg.logit_softcap)
         return logits[:, :cfg.vocab].astype(jnp.float32), new_cache
+
+
+def decode_stack(blocks, x, cache, cfg: LMConfig, kind: str, *, position,
+                 active=None, enc_out=None):
+    """One token through a scan-stacked layer stack: x [B, 1, D] and the
+    stacked cache ([L, B, ...] leaves) -> (y, new cache).
+
+    The cache rides in the scan's carry and each layer writes its own row
+    of it in place; it is never sliced out as the scan's input nor
+    stacked again as its output."""
+    def body(carry, inp):
+        h, c = carry
+        layer_params, layer = inp
+        h, c = apply_block_decode(
+            layer_params, h, c, cfg, kind, position=position,
+            enc_out=enc_out, use_rope=(kind != "rwkv"), active=active,
+            layer=layer)
+        return (h, c), None
+
+    n = jax.tree.leaves(blocks)[0].shape[0]
+    (x, cache), _ = jax.lax.scan(body, (x, cache),
+                                 (blocks, jnp.arange(n)))
+    return x, cache

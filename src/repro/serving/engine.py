@@ -4,20 +4,26 @@ The engine owns a ``kv`` slot arena of ``slots`` lanes and runs ONE
 jitted decode-plus-sample program per step regardless of which requests
 occupy which slots:
 
-* each lane decodes its own slot at its own position (a ``vmap`` of the
-  batch-1 ``model.decode_step`` over the arena's slot axes — bit-exact
-  vs a solo batch-1 decode for f32 dense/rwkv stacks, which is what the
-  equivalence tests pin);
+* the model's batched ``decode_step`` runs once over the whole arena
+  with a per-lane position vector: each lane ropes, masks and writes at
+  its own position (and a MoE routes each lane's token alone),
+  bit-exact vs a solo batch-1 decode for the f32 stacks the equivalence
+  tests pin (global KV, local ring, rwkv, MoE);
+* the arena is donated to the step and to the row write of an
+  admission: a decode step writes one token's K and V per lane per layer
+  into it in place, and nothing else of it (``arena_inplace`` counts the
+  programs that consumed the arena they were given);
 * temperature sampling runs INSIDE the jit with per-request keys
   (``fold_in(fold_in(key(seed), rid), token_index)``) — reproducible
   and independent of slot assignment and batch composition;
-* inactive lanes are inert: masked cache writes, held positions, held
-  tokens — a freed slot decodes garbage that is never observed and is
-  fully overwritten at the next admit.
+* inactive lanes are held: their tokens, positions and recurrent states
+  stay as they were; their K/V token lands at their held position in
+  their own slot, which is never read before it is written again.
 
 Prefill is chunked through the scheduler: one length-bucketed chunk
 (``LM.prefill_with_cache`` at the bucket's exact prompt length — no
-padding, bit-identical to each request's solo prefill) is interleaved
+padding, bit-identical to each request's solo prefill; a MoE's prompts
+each alone, under a vmap, so no expert's capacity is shared) is interleaved
 with decode steps under the chunk token budget, so long prompt bursts
 do not stall in-flight decodes.
 
@@ -135,58 +141,70 @@ class ServingEngine:
         self.engine_units = 0                     # modeled lane-tokens
         self.lane_steps = 0                       # active lanes, summed
 
-        self._step = jax.jit(self._build_step())
+        self.arena_inplace = 0                    # arena programs in place
+
+        # the arena (argument 1 of the step, 0 of the row write) is
+        # donated: each program writes into it and returns it
+        self._step = jax.jit(self._build_step(), donate_argnums=1)
         self._prefill = jax.jit(self._prefill_bucket)
         self._take_row = jax.jit(_arena(
             lambda tree, i: kv.take_slot(tree, self.axes, i)))
         self._put_row = jax.jit(_arena(
-            lambda tree, row, s: kv.put_slot(tree, self.axes, row, s)))
+            lambda tree, row, s: kv.put_slot(tree, self.axes, row, s)),
+            donate_argnums=0)
 
     # -- jitted programs -----------------------------------------------------
 
     def _build_step(self):
-        model, axes = self.model, self.axes
+        model = self.model
         temperature, seed = self.temperature, self.seed
+
+        def sample(logits, rs, ti):
+            key = jax.random.fold_in(
+                jax.random.fold_in(jax.random.key(seed), rs), ti)
+            return jax.random.categorical(key, logits / temperature,
+                                          axis=-1)
 
         def step(params, cache, positions, active, tokens, req_seed,
                  tok_idx):
-            def lane(row, pos, tok, rs, ti):
-                with jax.named_scope("arena"):
-                    cache_b = kv.expand_slot(row, axes)
-                logits, new_cache = model.decode_step(
-                    params, tok[None, None], cache_b, pos)
-                logits = logits[0]
-                if temperature > 0:
-                    key = jax.random.fold_in(
-                        jax.random.fold_in(jax.random.key(seed), rs), ti)
-                    nxt = jax.random.categorical(
-                        key, logits / temperature, axis=-1)
-                else:
-                    nxt = jnp.argmax(logits, axis=-1)
-                with jax.named_scope("arena"):
-                    row = kv.squeeze_slot(new_cache, axes)
-                return row, nxt.astype(jnp.int32)
-
-            new_cache, nxt = jax.vmap(
-                lane, in_axes=(axes, 0, 0, 0, 0),
-                out_axes=(axes, 0))(cache, positions, tokens, req_seed,
-                                    tok_idx)
-            with jax.named_scope("arena"):
-                new_cache = kv.where_slots(active, new_cache, cache, axes)
-            nxt = jnp.where(active, nxt, tokens)
-            return new_cache, nxt
+            logits, cache = model.decode_step(
+                params, tokens[:, None], cache, positions, active=active)
+            if temperature > 0:
+                nxt = jax.vmap(sample)(logits, req_seed, tok_idx)
+            else:
+                nxt = jnp.argmax(logits, axis=-1)
+            nxt = jnp.where(active, nxt.astype(jnp.int32), tokens)
+            return cache, nxt
 
         return step
 
     def _prefill_bucket(self, params, tokens):
         """Bucket prefill + greedy seed token (argmax of the prefill
         logits — fed to the first decode, never emitted, matching the
-        static serve path)."""
-        logits, serve_state = self.model.prefill_with_cache(
-            params, {"tokens": tokens}, cache_len=self.cache_len,
-            cache_dtype=self.cache_dtype)
+        static serve path).
+
+        A MoE stack prefills each prompt alone (a vmap of batch-1
+        prefills): its expert capacity counts every token dispatched
+        together, so one batched prefill could drop a prompt's tokens
+        for its neighbours'."""
+        model, axes = self.model, self.axes
+
+        def prefill(t):
+            logits, serve_state = model.prefill_with_cache(
+                params, {"tokens": t}, cache_len=self.cache_len,
+                cache_dtype=self.cache_dtype)
+            return logits, serve_state["cache"]
+
+        if model.cfg.is_moe:
+            def one(t):
+                logits, cache = prefill(t[None])
+                return logits[0], kv.take_slot(cache, axes, 0)
+
+            logits, cache = jax.vmap(one, out_axes=(0, axes))(tokens)
+        else:
+            logits, cache = prefill(tokens)
         tok0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return serve_state["cache"], tok0
+        return cache, tok0
 
     # -- request intake ------------------------------------------------------
 
@@ -209,7 +227,9 @@ class ServingEngine:
         for i, req in enumerate(chunk):
             slot = self.freelist.alloc()
             row = self._take_row(bucket_cache, i)
+            old = jax.tree.leaves(self.cache)
             self.cache = self._put_row(self.cache, row, slot)
+            self._count_inplace(old)
             self.positions[slot] = req.prompt_len
             self.active[slot] = True
             self.tokens[slot] = tok0[i]
@@ -219,11 +239,19 @@ class ServingEngine:
             self.outputs[req.rid] = []
             self.qos.record_admit(req.rid, self.decode_steps)
 
+    def _count_inplace(self, old) -> None:
+        """Count an arena program in ``arena_inplace`` when it consumed
+        every arena leaf ``old`` it was given: it wrote into the donated
+        buffers, not into a copy."""
+        self.arena_inplace += all(a.is_deleted() for a in old)
+
     def _decode_once(self) -> None:
+        old = jax.tree.leaves(self.cache)
         self.cache, nxt = self._step(
             self.params, self.cache, jnp.asarray(self.positions),
             jnp.asarray(self.active), jnp.asarray(self.tokens),
             jnp.asarray(self.req_seed), jnp.asarray(self.tok_idx))
+        self._count_inplace(old)
         with TraceAnnotation("engine.sync"):
             nxt = np.asarray(nxt)
         self.decode_steps += 1
@@ -294,6 +322,7 @@ class ServingEngine:
             "prefill_chunks": self.prefill_chunks,
             "engine_units": self.engine_units,
             "lane_steps": self.lane_steps,
+            "arena_inplace": self.arena_inplace,
             "occupancy_mean": (self.lane_steps / self.decode_steps
                                if self.decode_steps else 0.0),
             "qos": self.qos.snapshot(),

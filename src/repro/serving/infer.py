@@ -129,23 +129,14 @@ class SplitDecode:
 
     def ue_decode(self, ue_params, tok, cache, position):
         """tok [B, 1] -> (cut activation [B, 1, d], new ue cache)."""
-        import jax
         import jax.numpy as jnp
 
-        from repro.models.blocks import apply_block_decode
-        cfg, kind = self.cfg, self.kind
+        from repro.models.lm import decode_stack
+        cfg = self.cfg
         dt = jnp.dtype(cfg.dtype)
         x = self.model._embed({"embed": ue_params["embed"]}, tok, dt)
-
-        def body(carry, inp):
-            layer_params, st = inp
-            y, st_new = apply_block_decode(
-                layer_params, carry, st, cfg, kind, position=position,
-                use_rope=(kind != "rwkv"))
-            return y, st_new
-
-        x, new_cache = jax.lax.scan(body, x, (ue_params["blocks"], cache))
-        return x, new_cache
+        return decode_stack(ue_params["blocks"], x, cache, cfg, self.kind,
+                            position=position)
 
     # -- BS half -------------------------------------------------------------
 
@@ -185,24 +176,15 @@ class SplitDecode:
 
     def bs_decode(self, bs_params, act, cache, position):
         """Cut activation [B, 1, d] -> (logits [B, V], new bs cache)."""
-        import jax
         import jax.numpy as jnp
 
-        from repro.models.blocks import apply_block_decode
         from repro.models.common import apply_norm
-        from repro.models.lm import _softcap
-        cfg, kind = self.cfg, self.kind
+        from repro.models.lm import _softcap, decode_stack
+        cfg = self.cfg
         dt = jnp.dtype(cfg.dtype)
-        x = act.astype(dt)
-
-        def body(carry, inp):
-            layer_params, st = inp
-            y, st_new = apply_block_decode(
-                layer_params, carry, st, cfg, kind, position=position,
-                use_rope=(kind != "rwkv"))
-            return y, st_new
-
-        x, new_cache = jax.lax.scan(body, x, (bs_params["blocks"], cache))
+        x, new_cache = decode_stack(bs_params["blocks"], act.astype(dt),
+                                    cache, cfg, self.kind,
+                                    position=position)
         x = apply_norm(x, bs_params["final_norm"], cfg.norm)
         logits = _softcap(x[:, 0] @ bs_params["head"].astype(dt),
                           cfg.logit_softcap)

@@ -13,9 +13,12 @@ fixed-shape program while requests join and leave at arbitrary steps.
 ``slot_axes`` discovers the per-leaf slot axis structurally (two
 ``eval_shape`` probes at coprime batch sizes — the axis that moved is
 the batch axis), so the arena works for every family without a
-per-model axis table.  All mutation helpers are pure jax functions of
-``(tree, axes)`` — the engine jits them once; ``FreeList`` is the
-host-side slot allocator.
+per-model axis table.  ``take_slot``/``put_slot`` are pure jax functions
+of ``(tree, axes)``; the engine jits them once, ``put_slot`` with the
+arena donated, so admitting a request writes its one row in place.  The
+decode step does not go through these helpers: the model's batched
+``decode_step`` writes one token per lane per layer straight into the
+donated arena.  ``FreeList`` is the host-side slot allocator.
 """
 from __future__ import annotations
 
@@ -69,28 +72,6 @@ def put_slot(tree, axes, row, index):
         lambda a, r, ax: jax.lax.dynamic_update_index_in_dim(
             a, r.astype(a.dtype), index, ax),
         tree, row, axes)
-
-
-def expand_slot(row, axes):
-    """Re-insert a size-1 slot axis so a ``take_slot`` row can be fed to
-    the model's batch-shaped decode step (batch = 1 lane)."""
-    return jax.tree.map(lambda a, ax: jnp.expand_dims(a, ax), row, axes)
-
-
-def squeeze_slot(tree, axes):
-    """Inverse of ``expand_slot``."""
-    return jax.tree.map(lambda a, ax: jnp.squeeze(a, ax), tree, axes)
-
-
-def where_slots(mask, new, old, axes):
-    """Per-slot masked write: leaf ``ax``-indexed rows keep ``new`` where
-    ``mask`` is True, ``old`` otherwise — the merge that makes inactive
-    slots inert inside the fixed-shape decode step."""
-    def one(n, o, ax):
-        shape = [1] * n.ndim
-        shape[ax] = mask.shape[0]
-        return jnp.where(mask.reshape(shape), n, o)
-    return jax.tree.map(one, new, old, axes)
 
 
 class FreeList:

@@ -29,6 +29,26 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 CFG = LMConfig(name="serve-test", num_layers=2, d_model=32, n_heads=2,
                n_kv=1, d_ff=32, vocab=64, dtype="float32")
+# one stack per decode-state family: a global KV cache (CFG), a local
+# ring buffer shorter than the requests, and rwkv recurrent states
+FAMILIES = {
+    "attn": CFG,
+    "local": LMConfig(name="serve-local", num_layers=2, d_model=32,
+                      n_heads=2, n_kv=1, d_ff=32, vocab=64,
+                      pattern=("local",) * 2, window=4, dtype="float32"),
+    "rwkv": LMConfig(name="serve-rwkv", family="ssm", num_layers=2,
+                     d_model=32, n_heads=2, n_kv=2, d_ff=32, vocab=64,
+                     pattern=("rwkv",) * 2, rwkv_head_dim=16, rwkv_lora=8,
+                     norm="layernorm", dtype="float32"),
+    "moe": LMConfig(name="serve-moe", family="moe", num_layers=2,
+                    d_model=32, n_heads=2, n_kv=1, d_ff=32, vocab=64,
+                    moe_experts=2, moe_topk=1, moe_capacity=1.0,
+                    dtype="float32"),
+}
+# the MoE stack is served on 16 slots: 16 tokens over 2 experts outgrow
+# an expert capacity of 8, so a lane's token would be dropped if its
+# dispatch counted the other lanes' tokens
+SLOTS = {"moe": 16}
 
 
 @pytest.fixture(scope="module")
@@ -49,17 +69,26 @@ def _reqs(specs):
 # ---------------------------------------------------------------------------
 
 
-def test_staggered_requests_bitexact_vs_solo(model_params):
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_staggered_requests_bitexact_vs_solo(family):
     """Requests submitted mid-flight, ragged gens forcing slot churn on
-    a 2-slot arena: every output equals the solo batch=1 decode."""
-    model, params = model_params
-    reqs = _reqs([(3, 4), (5, 2), (3, 6), (4, 3), (5, 5)])
-    eng = ServingEngine(model, params, slots=2, cache_len=16)
-    for r in reqs[:2]:
+    a 2-slot arena: every output equals the solo batch=1 decode, for a
+    global KV cache, a local ring buffer (the requests outrun its
+    window) and rwkv states, each lane at its own position; and for a
+    MoE stack on a full 16-slot arena, whose lanes' tokens share the
+    experts."""
+    model = LM(FAMILIES[family])
+    params = model.init(jax.random.key(0))
+    slots = SLOTS.get(family, 2)
+    # 5 requests for 2 slots, 20 for 16
+    reqs = _reqs([(3, 4), (5, 2), (3, 6), (4, 3), (5, 5)]
+                 * max(1, slots // 4))
+    eng = ServingEngine(model, params, slots=slots, cache_len=16)
+    for r in reqs[:slots]:
         assert eng.submit(r)
     for _ in range(3):                       # r1 (gen 2) frees its slot
         eng.step_once()
-    for r in reqs[2:]:
+    for r in reqs[slots:]:
         assert eng.submit(r)
     out = eng.run()
     assert set(out) == {r.rid for r in reqs}
@@ -69,8 +98,62 @@ def test_staggered_requests_bitexact_vs_solo(model_params):
         np.testing.assert_array_equal(out[r.rid], ref)
     stats = eng.stats()
     assert stats["qos"]["completed"] == len(reqs)
-    # 2-slot arena, 5 tenants -> slots were reused
-    assert stats["decode_steps"] * 2 >= sum(r.max_new_tokens for r in reqs)
+    # more tenants than slots -> slots were reused
+    assert stats["decode_steps"] * slots >= sum(r.max_new_tokens
+                                                for r in reqs)
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_decode_step_position_vector_equals_scalar(family):
+    """A [B] vector of equal positions is the scalar call, bit for bit;
+    with distinct positions each lane equals the scalar call at its own
+    position, logits and cache."""
+    model = LM(FAMILIES[family])
+    params = model.init(jax.random.key(1))
+    prompts = jnp.asarray(
+        np.random.default_rng(4).integers(0, 64, (3, 6)), jnp.int32)
+    _, state = jax.jit(model.prefill_with_cache,
+                       static_argnames=("cache_len", "cache_dtype"))(
+        params, {"tokens": prompts}, cache_len=8, cache_dtype=jnp.float32)
+    tok = prompts[:, -1:]
+    decode = jax.jit(model.decode_step)
+    axes = kv.slot_axes(model, 8)
+
+    def lane(out, i):
+        logits, cache = out
+        return [logits[i]] + jax.tree.leaves(kv.take_slot(cache, axes, i))
+
+    def same(a, b):
+        for x, y in zip(a, b):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+    scalar = {p: decode(params, tok, state["cache"], jnp.int32(p))
+              for p in (4, 5, 6)}
+    equal = decode(params, tok, state["cache"], jnp.full((3,), 6, jnp.int32))
+    same(jax.tree.leaves(equal), jax.tree.leaves(scalar[6]))
+    ragged = decode(params, tok, state["cache"],
+                    jnp.asarray([6, 4, 5], jnp.int32))
+    for i, p in enumerate((6, 4, 5)):
+        same(lane(ragged, i), lane(scalar[p], i))
+
+
+def test_arena_programs_run_in_place(model_params):
+    """Every decode step and every row write consumes the arena it is
+    given (the donated buffers are written, not copied):
+    ``arena_inplace`` counts decode steps plus admitted rows."""
+    model, params = model_params
+    reqs = _reqs([(3, 4), (5, 2), (3, 6), (4, 3)])
+    eng = ServingEngine(model, params, slots=2, cache_len=16)
+    eng.submit(reqs[0])
+    eng.step_once()
+    held = jax.tree.leaves(eng.cache)
+    assert not any(a.is_deleted() for a in held)
+    eng.step_once()
+    assert all(a.is_deleted() for a in held)
+    eng.run(reqs[1:])
+    stats = eng.stats()
+    assert stats["qos"]["admitted"] == len(reqs)
+    assert stats["arena_inplace"] == stats["decode_steps"] + len(reqs)
 
 
 def test_sampled_bitexact_and_slot_independent(model_params):
@@ -213,10 +296,9 @@ def test_slot_axes_take_put_roundtrip(model_params):
     back = kv.put_slot(cache, axes, row, 1)
     for a, b in zip(jax.tree.leaves(cache), jax.tree.leaves(back)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    # expand/squeeze invert each other
-    b1 = kv.expand_slot(row, axes)
-    row2 = kv.squeeze_slot(b1, axes)
-    for a, b in zip(jax.tree.leaves(row), jax.tree.leaves(row2)):
+    # a row written into another slot reads back from there
+    moved = kv.take_slot(kv.put_slot(cache, axes, row, 2), axes, 2)
+    for a, b in zip(jax.tree.leaves(row), jax.tree.leaves(moved)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 

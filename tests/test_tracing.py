@@ -170,17 +170,39 @@ def _requests(specs):
             for i, (plen, gen) in enumerate(specs)]
 
 
-def test_decode_step_merges_the_arena_under_its_scope(model_params):
+def _aliased_params(text: str) -> set:
+    """Parameter numbers a compiled module's outputs alias (donation)."""
+    head = text.split("\n", 1)[0]
+    return {int(p) for p in re.findall(r"\}: \((\d+), \{\}", head)}
+
+
+def _arena_params(eng, args_before) -> set:
+    """Parameter numbers of the arena's leaves among the flattened
+    arguments, which start with ``args_before`` (a pytree)."""
+    first = len(jax.tree.leaves(args_before))
+    return set(range(first, first + len(jax.tree.leaves(eng.cache))))
+
+
+def test_decode_step_writes_the_arena_in_place_under_its_scope(
+        model_params):
+    """The decode step aliases every arena leaf from input to output,
+    writes it one token per lane under the ``arena`` scope, and selects,
+    copies or transposes nothing of an arena leaf's shape."""
     eng = _engine(model_params)
     args = (eng.params, eng.cache, jnp.asarray(eng.positions),
             jnp.asarray(eng.active), jnp.asarray(eng.tokens),
             jnp.asarray(eng.req_seed), jnp.asarray(eng.tok_idx))
     text = eng._step.lower(*args).compile().as_text()
-    leaf = jax.tree.leaves(eng.cache)[0]
-    arena = f"f32[{','.join(map(str, leaf.shape))}]"
-    merges = [op for shape, op in _ops(text, "select") if shape == arena]
-    assert merges, f"no select over the arena's {arena} leaves"
-    assert all("arena" in _scopes(op) for op in merges), merges
+    assert _arena_params(eng, eng.params) <= _aliased_params(text)
+    arenas = {f"f32[{','.join(map(str, a.shape))}]"
+              for a in jax.tree.leaves(eng.cache)}
+    for opcode in ("select", "copy", "transpose"):
+        moved = [op for shape, op in _ops(text, opcode) if shape in arenas]
+        assert not moved, (opcode, moved)
+    writes = [op for shape, op in _ops(text, "dynamic-update-slice")
+              if shape in arenas]
+    assert writes, f"no write into the arena's {arenas} leaves"
+    assert all("arena" in _scopes(op) for op in writes), writes
     assert eng._step.lower(*args).as_text().startswith("module @jit_step")
 
 
@@ -192,6 +214,9 @@ def test_row_copies_run_under_the_arena_scope(model_params):
     for lowered in (take, put):
         names = _OP_NAME.findall(lowered.compile().as_text())
         assert names and any("arena" in _scopes(n) for n in names)
+    # the row write is donated the arena and writes it in place
+    assert _arena_params(eng, ()) <= _aliased_params(
+        put.compile().as_text())
 
 
 def test_engine_spans_nest_in_a_profiler_trace(model_params, tmp_path):
