@@ -37,9 +37,8 @@ def _seed(i: int) -> int:
 
 
 def calibrate_train(cell, devices, seeds, faults) -> list:
-    from harness import reference, train
+    from harness import train
     tr = cell.traffic
-    adam = (tr["lr"], tr["b1"], tr["b2"], tr["eps"])
     n = tr["ref_steps"]
     prog = train.program(cell, devices)
     out = []
@@ -50,8 +49,7 @@ def calibrate_train(cell, devices, seeds, faults) -> list:
                                        feed, seed)
         state = feed = None
         want = train.reference_readings(
-            reference.Reference(cell.config, seed, devices, adam=adam),
-            rows, n)
+            train.reference(cell, seed, devices), rows, n)
         rec = {"seed": seed, "program": _vals(train.compare(
             got, want, cell.limits)), "ref_losses": want["losses"],
             "program_losses": got["losses"],
@@ -63,8 +61,8 @@ def calibrate_train(cell, devices, seeds, faults) -> list:
             if len(devices) > 1:
                 variants["no_exchange"] = {"drop_exchange": True}
             for name, kw in variants.items():
-                alt = train.reference_readings(reference.Reference(
-                    cell.config, seed, devices, adam=adam, **kw), rows, n)
+                alt = train.reference_readings(
+                    train.reference(cell, seed, devices, **kw), rows, n)
                 rec[name] = _vals(train.compare(alt, want, cell.limits))
         rec["seconds"] = time.perf_counter() - t0
         print(json.dumps(rec), flush=True)
@@ -74,7 +72,7 @@ def calibrate_train(cell, devices, seeds, faults) -> list:
 
 def calibrate_serve(cell, devices, seeds, faults, seconds) -> list:
     import numpy as np
-    from harness import reference, serve
+    from harness import serve, spec
     from harness.clock import CompileClock
     clock = CompileClock()
     out = []
@@ -87,9 +85,10 @@ def calibrate_serve(cell, devices, seeds, faults, seconds) -> list:
                "ttft_p95_ms": run["ttft_p95_ms"],
                "itl_p95_ms": run["itl_p95_ms"]}
         if i < faults:
-            ref = reference.Reference(cell.config, seed, devices[:1])
-            ctl = reference.Reference(cell.config, seed, devices[:1],
-                                      precision="fp8")
+            fam = spec.family(cell.config)
+            ref = fam.Reference(cell.config, seed, devices[:1])
+            ctl = fam.Reference(cell.config, seed, devices[:1],
+                                precision="fp8")
             rec["control"] = {"served_logit_gap": serve.served_gap(
                 ref, run["checked"], cell.traffic["cache_len"],
                 control=ctl)[0]}

@@ -2,7 +2,10 @@
 
 Counts are of the work the model needs, whatever implements it: no
 recomputation, no masked pipeline ticks, and one copy of a replicated
-head.  ``cfg`` is a configuration file's dict (Hugging Face keys).
+head.  ``cfg`` is a configuration file's dict (Hugging Face keys) of a
+dense decoder; a family module (``bench/families/``) serves these counts
+for its configurations or counts its own.  ``codec_bytes`` is the hop's,
+whatever the family.
 """
 from __future__ import annotations
 

@@ -25,7 +25,8 @@ import numpy as np
 
 from harness import trace
 
-#: the program's named scopes, by layer
+#: the program's named scopes, by layer; a reader may ask for any other
+#: name the program gives a scope (``with_scope``)
 SCOPES = ("attention", "mlp", "head_loss", "pipeline.hop", "arena",
           "embed", "grad_accum", "optimizer")
 #: ``ServingEngine``'s host spans; ``engine.step`` holds the others
@@ -86,8 +87,8 @@ def op_names(hlo_text: str) -> dict:
     return names
 
 
-def scope_of(op_name: str):
-    """The innermost of ``SCOPES`` on the ``op_name`` path, else None.  A
+def scope_of(op_name: str, names=SCOPES):
+    """The innermost of ``names`` on the ``op_name`` path, else None.  A
     path component may be wrapped by a transformation:
     ``transpose(jvp(attention))`` is ``attention``."""
     found = None
@@ -97,14 +98,23 @@ def scope_of(op_name: str):
             if not m:
                 break
             comp = m.group(1)
-        if comp in SCOPES:
+        if comp in names:
             found = comp
     return found
 
 
-def scope_table(hlo_text: str) -> dict:
-    """``{instruction: scope or None}`` of a compiled module."""
-    return {i: scope_of(n) for i, n in op_names(hlo_text).items()}
+def scope_table(hlo_text: str, names=SCOPES) -> dict:
+    """``{instruction: scope or None}`` of a compiled module, over the
+    scopes ``names``."""
+    return {i: scope_of(n, names) for i, n in op_names(hlo_text).items()}
+
+
+def with_scope(scope: str) -> tuple:
+    """``SCOPES``, and ``scope`` if it is not among them: the names a
+    table is built over to read ``scope``.  Innermost wins, so a scope
+    nested in one of ``SCOPES`` (``mlp/moe.experts``) takes its own
+    operations from the outer one only in a table built to read it."""
+    return SCOPES if scope in SCOPES else SCOPES + (scope,)
 
 
 def instruction(table: dict, op: str):
@@ -145,12 +155,13 @@ def module_ops(summary, module):
     return out
 
 
-def by_scope(summary, hlo_text: str, module, top: int = 10) -> dict:
-    """Device seconds of the programs ``module`` matches, by scope
-    (``None``: outside every scope), the operations outside every scope
-    that took most time, and the share of operations whose instruction
-    was not found in the HLO; each averaged over the chips."""
-    table = scope_table(hlo_text)
+def by_scope(summary, hlo_text: str, module, top: int = 10,
+             names=SCOPES) -> dict:
+    """Device seconds of the programs ``module`` matches, by scope of
+    ``names`` (``None``: outside every scope), the operations outside
+    every scope that took most time, and the share of operations whose
+    instruction was not found in the HLO; each averaged over the chips."""
+    table = scope_table(hlo_text, names)
     per_chip = module_ops(summary, module)
     n = len(per_chip)
     seconds, outside = {}, {}
@@ -171,9 +182,11 @@ def by_scope(summary, hlo_text: str, module, top: int = 10) -> dict:
 
 
 def scope_seconds(summary, hlo_text: str, scope: str, module) -> float:
-    """Device seconds of the window's operations under ``scope`` in the
-    programs ``module`` matches, averaged over the chips."""
-    return by_scope(summary, hlo_text, module)["seconds"].get(scope, 0.0)
+    """Device seconds of the window's operations under ``scope`` (any
+    name the program gives a scope: ``with_scope``) in the programs
+    ``module`` matches, averaged over the chips."""
+    return by_scope(summary, hlo_text, module,
+                    names=with_scope(scope))["seconds"].get(scope, 0.0)
 
 
 def module_runs(summary, module) -> float:
@@ -243,7 +256,8 @@ def decode_step_hlo(cell, devices) -> str:
     from repro.serving.engine import ServingEngine
     tr = cell.traffic
     one = jax.sharding.SingleDeviceSharding(devices[0])
-    model = LM(spec.lm_config(cell.config, name=cell.config_name))
+    model = LM(spec.family(cell.config).lm_config(cell.config,
+                                                  name=cell.config_name))
     params = jax.eval_shape(model.init, jax.random.key(0))
     params = _shaped(params, jax.tree.map(lambda _: one, params))
     cache = jax.eval_shape(lambda: model.init_cache(
@@ -273,8 +287,9 @@ def program_hlo(obs, build) -> str:
 
 
 def train_scope_ms(obs, scope: str):
-    """Device ms per train step of the operations under ``scope``,
-    averaged over the chips; None where the program has no such scope."""
+    """Device ms per train step of the operations under ``scope`` (in
+    ``SCOPES`` or not), averaged over the chips; None where the program
+    has no such scope."""
     if obs.trace is None or obs.cell.traffic["kind"] != "train" \
             or not obs.run.get("steps"):
         return None
@@ -284,8 +299,9 @@ def train_scope_ms(obs, scope: str):
 
 
 def decode_scope_ms(obs, scope: str):
-    """Device ms per decode step of the operations under ``scope`` in the
-    engine's ``jit_step``; None where the program has no such scope."""
+    """Device ms per decode step of the operations under ``scope`` (in
+    ``SCOPES`` or not) in the engine's ``jit_step``; None where the
+    program has no such scope."""
     if obs.trace is None or obs.cell.traffic["kind"] != "serve":
         return None
     runs = module_runs(obs.trace, DECODE_STEP)

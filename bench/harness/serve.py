@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness import reference, spec, stats, weights
+from harness import spec, stats, weights
 from harness.clock import CompileClock
 
 
@@ -72,8 +72,8 @@ def build(cell, devices, seed: int):
     from repro.serving.engine import ServingEngine
 
     tr, cfg_json = cell.traffic, cell.config
-    cfg = spec.lm_config(cfg_json, name=cell.config_name)
-    model = LM(cfg)
+    model = LM(spec.family(cfg_json).lm_config(cfg_json,
+                                               name=cell.config_name))
     shapes = jax.eval_shape(model.init, jax.random.key(0))
     one = jax.sharding.SingleDeviceSharding(devices[0])
     params = jax.jit(lambda key: weights.make_params(key, shapes),
@@ -242,7 +242,7 @@ def run(cell, devices, seed: int, seconds: float, *, t_start: float,
     engine = None
     checked = [served[r] for r in sample_requests(served, seed,
                                                   tr["check_requests"])]
-    ref = reference.Reference(cfg_json, seed, devices[:1])
+    ref = spec.family(cfg_json).Reference(cfg_json, seed, devices[:1])
     gap, tokens = served_gap(ref, checked, tr["cache_len"])
     result.update(checked=checked, checked_tokens=tokens)
     result["checks"] = [("served_logit_gap", gap,

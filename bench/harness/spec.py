@@ -1,24 +1,52 @@
 """Find a cell's files by the names in ``BENCHMARK.json``.
 
 A cell names a configuration, a traffic mix and (through the per-layer
-metrics) readers; each is a file of its own:
+metrics) readers; each is a file of its own, and so is the model family
+that the configuration's ``model_type`` names:
 
     bench/configs/<config>.json     sizes, as run, with the source's keys
+    bench/families/<model_type>.py  the family: the program's LMConfig,
+                                    the leaves, the plain reference and
+                                    the FLOP counts (``family``)
     bench/traffic/<traffic>.json    parameters for the module its "kind"
                                     names (harness/train.py, serve.py)
     bench/limits/<workload>.json    the limits that decide ``correct``
     bench/metrics/<metric>.py       one reader per per-layer metric
 
 Adding a cell is adding files and entries; no file here needs an edit.
+A configuration of a family the benchmark has not run costs a family
+file, a configuration file, its traffic, its limits, readers for what
+it adds, and the entries in ``BENCHMARK.json``; no file that is there
+changes.  A family module gives:
+
+    lm_config(config, name)            the program's ``LMConfig``
+    param_shapes(config)               leaf name -> shape; the program's
+                                       tree has to be this one
+    Reference(config, seed, devices, *, adam=None, precision="f32",
+              microbatches=1, rows=None, drop_exchange=False)
+                                       the plain reference: ``train_step``,
+                                       ``leaf_norms``, ``change_norms``
+                                       and, for serving cells, ``logits``
+                                       (``harness/reference.py`` has the
+                                       options' meaning)
+    train_flops_per_token(config, seq)
+    decode_step_least(config, positions, weight_itemsize, kv_itemsize)
+    prefill_flops(config, prompt_len)  the work, as ``harness/flops.py``
+                                       counts a dense decoder's
+    published(config)                  the published widths of the model
+                                       that the file's ``source`` names
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 import os
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
+#: where ``family`` finds ``<model_type>.py``
+FAMILIES_DIR = os.path.join(BENCH_DIR, "families")
 
 
 def _load(path: str) -> dict:
@@ -62,23 +90,21 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
         per_layer=[m for m in bench["per_layer"] if reports(m)])
 
 
-def lm_config(config: dict, name: str = "bench"):
-    """The program's ``LMConfig`` for a configuration file written with
-    the Hugging Face ``config.json`` keys of a dense decoder."""
-    from repro.models.config import LMConfig
-    if config.get("model_type") not in ("qwen2",):
-        raise ValueError(f"model_type {config.get('model_type')!r}: this "
-                         "harness maps only dense qwen2 decoders")
-    return LMConfig(
-        name=name, family="dense",
-        num_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"],
-        n_heads=config["num_attention_heads"],
-        n_kv=config["num_key_value_heads"],
-        d_ff=config["intermediate_size"],
-        vocab=config["vocab_size"],
-        qkv_bias=bool(config["attention_bias"]),
-        act=config["hidden_act"], norm="rmsnorm",
-        rope_theta=float(config["rope_theta"]),
-        tie_embeddings=bool(config["tie_word_embeddings"]),
-        dtype=config["torch_dtype"])
+_families: dict = {}
+
+
+def family(config: dict):
+    """The family module that the configuration's ``model_type`` names,
+    ``FAMILIES_DIR/<model_type>.py``, loaded once per file."""
+    model_type = config.get("model_type")
+    path = os.path.join(FAMILIES_DIR, f"{model_type}.py")
+    if path not in _families:
+        if not os.path.exists(path):
+            raise ValueError(f"model_type {model_type!r}: no family file "
+                             f"{os.path.relpath(path, ROOT)}")
+        mod_spec = importlib.util.spec_from_file_location(
+            "bench_family_" + str(model_type).replace(".", "_"), path)
+        mod = importlib.util.module_from_spec(mod_spec)
+        mod_spec.loader.exec_module(mod)
+        _families[path] = mod
+    return _families[path]

@@ -19,7 +19,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from harness import reference, spec, weights
+from harness import spec, weights
 from harness.clock import CompileClock
 
 FEED = 16      # distinct batches the window cycles through
@@ -46,11 +46,11 @@ def program(cell, devices, mesh=None):
     from repro.training.optim import adamw
 
     tr, cfg_json = cell.traffic, cell.config
-    cfg = spec.lm_config(cfg_json, name=cell.config_name)
-    model = LM(cfg)
+    fam = spec.family(cfg_json)
+    model = LM(fam.lm_config(cfg_json, name=cell.config_name))
     opt = adamw(tr["lr"], b1=tr["b1"], b2=tr["b2"], eps=tr["eps"])
     shapes = jax.eval_shape(model.init, jax.random.key(0))
-    want = reference.param_shapes(cfg_json)
+    want = fam.param_shapes(cfg_json)
     got = {n: tuple(s.shape) for n, s in
            zip(weights.leaf_names(shapes), jax.tree.leaves(shapes))}
     if got != want:
@@ -178,11 +178,21 @@ def run(cell, devices, seed: int, seconds: float, *, t_start: float,
 
     # free the program's state, then the reference's steps on the same rows
     state = feed = step_fn = prog = None
-    ref = reference.Reference(cell.config, seed, devices,
-                              adam=(tr["lr"], tr["b1"], tr["b2"], tr["eps"]))
-    result["checks"] = compare(readings, reference_readings(ref, rows, n_ref),
-                               cell.limits)
+    result["checks"] = compare(
+        readings, reference_readings(reference(cell, seed, devices), rows,
+                                     n_ref), cell.limits)
     return result
+
+
+def reference(cell, seed: int, devices, **options):
+    """The family's plain reference for this cell, with the traffic's
+    AdamW and micro-batches; ``options`` as ``Reference`` takes them
+    (``precision``, ``rows``, ``drop_exchange``)."""
+    tr = cell.traffic
+    return spec.family(cell.config).Reference(
+        cell.config, seed, devices,
+        adam=(tr["lr"], tr["b1"], tr["b2"], tr["eps"]),
+        microbatches=tr["microbatches"], **options)
 
 
 def reference_readings(ref, rows, n_steps: int) -> dict:

@@ -1,7 +1,8 @@
 """Per decode step, the device ms of the operations under the program's
-``arena`` scope in the engine's ``jit_step`` (``serving/engine.py``: each
-lane's slot expanded and squeezed, and the ``where_slots`` merge of the
-whole arena)."""
+``arena`` scope in the engine's ``jit_step`` (``models/blocks.py``
+``_write_token``: one token's keys and values per lane per layer,
+written by a dynamic-update-slice into the arena the engine donates to
+the step, and nothing else of the arena)."""
 from harness import scopes
 
 

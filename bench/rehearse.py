@@ -77,7 +77,7 @@ def rehearse_serve(cell, topo) -> dict:
     from harness import spec
     tr = cell.traffic
     one = jax.sharding.SingleDeviceSharding(topo.devices[0])
-    model = LM(spec.lm_config(cell.config))
+    model = LM(spec.family(cell.config).lm_config(cell.config))
     params = _with(jax.eval_shape(model.init, jax.random.key(0)),
                    jax.tree.map(lambda _: one, jax.eval_shape(
                        model.init, jax.random.key(0))))

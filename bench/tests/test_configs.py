@@ -1,4 +1,5 @@
-"""The configuration files against the program's registry of widths."""
+"""The configuration files against their families: the program's
+registry of widths, and the published widths that each family gives."""
 import dataclasses
 import json
 import os
@@ -9,8 +10,6 @@ from harness import spec
 from repro.configs import get_arch
 
 BENCH = json.load(open(os.path.join(spec.ROOT, "BENCHMARK.json")))
-WIDTHS = ("hidden_size", "intermediate_size", "num_attention_heads",
-          "num_key_value_heads", "rms_norm_eps", "tie_word_embeddings")
 
 
 def _file(name):
@@ -21,27 +20,22 @@ def _file(name):
 def test_one_chip_config_is_the_registrys_chip_share():
     cfg, _ = _file("qwen1.5-4b")
     want = get_arch("qwen1.5-4b").config("chip")
-    got = spec.lm_config(cfg, name=want.name)
+    got = spec.family(cfg).lm_config(cfg, name=want.name)
     assert got == want
 
 
 def test_four_stage_config_is_four_chip_shares():
     cfg, _ = _file("qwen1.5-4b-4stage")
     chip = get_arch("qwen1.5-4b").config("chip")
-    got = spec.lm_config(cfg, name=chip.name)
+    got = spec.family(cfg).lm_config(cfg, name=chip.name)
     assert got == dataclasses.replace(chip, num_layers=4 * chip.num_layers)
 
 
 @pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])
 def test_config_keeps_published_widths_and_lists_its_cuts(name):
     cfg, entry = _file(name)
-    full = get_arch("qwen1.5-4b").full
-    published = {"hidden_size": full.d_model, "intermediate_size": full.d_ff,
-                 "num_attention_heads": full.n_heads,
-                 "num_key_value_heads": full.n_kv,
-                 "num_hidden_layers": full.num_layers,
-                 "vocab_size": full.vocab, "tie_word_embeddings": False}
+    published = spec.family(cfg).published(cfg)
     changed = sorted(k for k, v in published.items() if cfg[k] != v)
     assert changed == sorted(entry["reduced"]) == sorted(cfg["reduced"])
     for key in cfg["reduced"]:
-        assert cfg["reduced"][key][1] == cfg[key]
+        assert cfg["reduced"][key][:2] == [published[key], cfg[key]]

@@ -39,7 +39,8 @@ def test_reference_draws_the_programs_weights():
     from harness import spec
     from repro.models.lm import LM
     cfg = tiny.CONFIG
-    shapes = jax.eval_shape(LM(spec.lm_config(cfg)).init, jax.random.key(0))
+    shapes = jax.eval_shape(LM(spec.family(cfg).lm_config(cfg)).init,
+                            jax.random.key(0))
     seed = 2 ** 40 + 9
     prog = jax.jit(lambda k: weights.make_params(k, shapes))(
         weights.seed_key(seed))

@@ -46,6 +46,36 @@ def test_recorded_scopes_split_the_program(recorded):
     assert split["total"] == pytest.approx(busy, rel=1e-6)
 
 
+def test_recorded_readings_are_todays(recorded):
+    """Over ``SCOPES`` the table reads what it read before a reader could
+    name a scope of its own."""
+    summary, hlo = recorded
+    assert scopes.scope_seconds(summary, hlo, "attention", TOY) == 4.4316e-05
+    assert scopes.scope_seconds(summary, hlo, "mlp", TOY) == 3.5558e-05
+    assert scopes.by_scope(summary, hlo, TOY)["seconds"] == {
+        None: 4.9e-08, "attention": 4.4316e-05, "mlp": 3.5558e-05}
+
+
+def test_recorded_nested_scope_reads_its_own_time(recorded):
+    """A scope outside ``SCOPES``, nested in ``mlp`` (the recorded HLO
+    with the toy's second matmul put under ``mlp/mlp.matmul``), reads
+    its own operations' time; ``mlp`` still reads the whole scope."""
+    summary, hlo = recorded
+    nested = hlo.replace('op_name="jit(toy)/mlp/dot_general"',
+                         'op_name="jit(toy)/mlp/mlp.matmul/dot_general"')
+    assert nested != hlo
+    assert scopes.scope_seconds(summary, nested, "mlp.matmul", TOY) == \
+        3.5558e-05
+    assert scopes.scope_seconds(summary, nested, "mlp", TOY) == 3.5558e-05
+    assert scopes.scope_seconds(summary, nested, "attention", TOY) == \
+        4.4316e-05
+    inner = scopes.by_scope(summary, nested, TOY,
+                            names=scopes.with_scope("mlp.matmul"))
+    assert inner["seconds"]["mlp.matmul"] == 3.5558e-05
+    assert inner["seconds"].get("mlp", 0.0) == 0.0      # innermost wins
+    assert scopes.scope_seconds(summary, hlo, "mlp.matmul", TOY) == 0.0
+
+
 def test_recorded_engine_spans_and_idle_within(recorded):
     summary, _ = recorded
     names = [n for n, _, _ in summary.host]
@@ -86,6 +116,19 @@ def test_engine_spans_stay_out_of_the_benchmarks_host_spans():
 ])
 def test_scope_of_unwraps_transformations(op_name, scope):
     assert scopes.scope_of(op_name) == scope
+
+
+@pytest.mark.parametrize("op_name,scope,want,over_scopes", [
+    ("jit(f)/transpose(jvp(mlp))/moe.experts/dot_general", "moe.experts",
+     "moe.experts", "mlp"),
+    ("jit(f)/mlp/router/dot_general", "moe.experts", "mlp", "mlp"),
+    ("jit(f)/moe.experts/attention/add", "moe.experts", "attention",
+     "attention"),
+    ("jit(f)/jvp(moe.experts)/add", "moe.experts", "moe.experts", None),
+])
+def test_scope_of_a_name_outside_scopes(op_name, scope, want, over_scopes):
+    assert scopes.scope_of(op_name, scopes.with_scope(scope)) == want
+    assert scopes.scope_of(op_name) == over_scopes
 
 
 def test_op_names_give_a_fusion_its_root_scope():
@@ -154,6 +197,28 @@ def test_train_readers_read_the_step_by_scope(name, traffic):
     assert metrics.read("arena_ms", bare) is None
     assert metrics.read("attention_ms", _obs(train_cell, None,
                                              steps=2)) is None
+
+
+def test_train_reader_of_a_nested_scope_outside_scopes():
+    """``train_scope_ms`` of a name the program nests in ``mlp`` (the
+    tiny step's MLP matmuls planted under ``mlp/mlp.dots``) reads those
+    operations, and ``mlp_ms`` reads the whole ``mlp`` as before."""
+    cell = tiny.cell("qwen4b-train-1chip", **tiny.TRAIN)
+    hlo = scopes.train_step_hlo(cell, jax.devices()[:1])
+    nested = re.sub(r'(op_name="[^"]*/mlp)/([^"/]*dot_general")',
+                    r"\1/mlp.dots/\2", hlo)
+    table, inner = (scopes.scope_table(nested),
+                    scopes.scope_table(nested, scopes.with_scope("mlp.dots")))
+    assert table == scopes.scope_table(hlo)
+    dots = sum(1 for s in inner.values() if s == "mlp.dots")
+    assert dots > 0
+    obs = _obs(cell, _stand_in_trace(nested, "jit_train_step", runs=2),
+               steps=2, **{"hlo:train_step_hlo": nested})
+    assert scopes.train_scope_ms(obs, "mlp.dots") == pytest.approx(
+        dots * 1e-3)
+    mlp = sum(1 for s in table.values() if s == "mlp")
+    assert metrics.read("mlp_ms", obs) == pytest.approx(mlp * 1e-3)
+    assert mlp > dots
 
 
 def test_arena_reader_reads_the_decode_step():
