@@ -44,10 +44,9 @@ def calibrate_train(cell, devices, seeds, faults) -> list:
     out = []
     for i, seed in enumerate(seeds):
         t0 = time.perf_counter()
-        state, feed, rows = train.materialize(prog, cell, seed)
-        got, state = train.first_steps(prog["step"], prog, cell, state,
-                                       feed, seed)
-        state = feed = None
+        feed, rows = train.materialize(prog, cell, seed)
+        got = train.first_steps(prog["step"], prog, cell, feed, seed)[0]
+        feed = None
         want = train.reference_readings(
             train.reference(cell, seed, devices), rows, n)
         rec = {"seed": seed, "program": _vals(train.compare(

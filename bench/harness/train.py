@@ -95,29 +95,34 @@ def program(cell, devices, mesh=None):
 
 
 def materialize(prog, cell, seed: int):
-    """The state drawn from the seed, placed as the program places it, and
-    the feed: ``FEED`` batches of distinct rows, on the device and (as
-    numpy) for the reference."""
+    """The feed drawn from the seed: ``FEED`` batches of distinct rows, on
+    the device and (as numpy) for the reference.  The state is drawn in
+    ``first_steps``, so that nothing but the step that consumes it holds
+    it."""
     tr = cell.traffic
-    state = prog["place"](jax.jit(prog["init"],
-                                  out_shardings=prog["shardings"])(
-        weights.seed_key(seed)))
     rows = [weights.token_rows(seed, tr["batch"], tr["seq"] + 1,
                                cell.config["vocab_size"], stream=i)
             for i in range(FEED)]
     feed = [jax.device_put({"tokens": r[:, :-1], "labels": r[:, 1:]},
                            prog["batch_sharding"]) for r in rows]
-    return state, feed, rows
+    return feed, rows
 
 
-def first_steps(step_fn, prog, cell, state, feed, seed: int):
-    """The first ``ref_steps`` steps (the first compiles); returns the
-    readings the reference is compared with, and the state."""
+def first_steps(step_fn, prog, cell, feed, seed: int):
+    """The first ``ref_steps`` steps (the first compiles) from the state
+    drawn from the seed and placed as the program places it; returns the
+    readings the reference is compared with, and the state.  The state is
+    drawn here and bound to one name, which each step rebinds: at every
+    call the device holds the step's input state and, while it runs, its
+    output, and never a third (the step is not donated)."""
     tr = cell.traffic
     grad_norms = _leaf_norms_fn(1.0 / (1.0 - tr["b1"]))
     change_norms = jax.jit(lambda p, key: jax.tree.map(
         lambda a, b: jnp.sqrt(jnp.sum(jnp.square(a - b))),
         p, weights.make_params(key, prog["shapes"])))
+    state = prog["place"](jax.jit(prog["init"],
+                                  out_shardings=prog["shardings"])(
+        weights.seed_key(seed)))
     losses = []
     for i in range(tr["ref_steps"]):
         state, mets = step_fn(state, feed[i])
@@ -137,10 +142,13 @@ def run(cell, devices, seed: int, seconds: float, *, t_start: float,
     n_ref = tr["ref_steps"]
     prog = program(cell, devices)
     step_fn = prog["step"] if wrap_step is None else wrap_step(prog["step"])
-    state, feed, rows = materialize(prog, cell, seed)
-    readings, state = first_steps(step_fn, prog, cell, state, feed, seed)
+    feed, rows = materialize(prog, cell, seed)
+    readings, state = first_steps(step_fn, prog, cell, feed, seed)
 
-    # the window: whole steps, one in flight, until `seconds` has passed
+    # the window: whole steps, one in flight, until `seconds` has passed.
+    # The queued step's output is allocated while the running step still
+    # holds its input: three states where they fit (`memory_peak_bytes`
+    # reads them); where they do not, the queued step waits.
     tokens = tr["batch"] * tr["seq"]
     compiles0, compile_s = clock.programs, clock.seconds
     result: dict = {}

@@ -47,7 +47,16 @@ def _with(shapes, shardings):
         s.shape, s.dtype, sharding=sh), shapes, shardings)
 
 
+def _on_chip(x) -> int:
+    """Elements of ``x``'s shard on one chip (the shards are alike)."""
+    return int(np.prod(x.sharding.shard_shape(x.shape)))
+
+
 def rehearse_train(cell, topo) -> dict:
+    """The step's memory as the chip's compiler counts it, and what a
+    training run costs at its peak: two states on the fullest chip (the
+    step's input and its output; the step is not donated) and the step's
+    temp, as ``bytes_per_parameter`` over the parameters held there."""
     from harness import train
     from repro.parallel.compat import make_mesh
     pipe = cell.traffic.get("pipeline")
@@ -64,7 +73,14 @@ def rehearse_train(cell, topo) -> dict:
     compiled = prog["step"].lower(state, {"tokens": tok,
                                           "labels": tok}).compile()
     text = compiled.as_text()
-    return {"train_step": _mem(compiled),
+    mem = _mem(compiled)
+    state_bytes = sum(_on_chip(x) * x.dtype.itemsize
+                      for x in jax.tree.leaves(state))
+    parameters = sum(_on_chip(x) for x in jax.tree.leaves(state["params"]))
+    return {"train_step": mem, "state_bytes": state_bytes,
+            "parameters": parameters,
+            "bytes_per_parameter": (2 * state_bytes
+                                    + mem["temp_size_in_bytes"]) / parameters,
             "tpu_custom_calls": text.count("tpu_custom_call"),
             "collective_permutes": text.count("collective-permute-start")
             or text.count("collective-permute(")}
