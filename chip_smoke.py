@@ -32,6 +32,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from repro.models.common import AttentionPaths  # noqa: E402
+
 ARCH = "qwen1.5-4b"
 
 #: ``LM.init`` draws the head from N(0, 0.02^2) and the final RMSNorm
@@ -73,11 +75,13 @@ def _fail(msg: str):
 
 
 def run_phase(clock, name: str, fn, **kwargs):
-    """Run one phase; print its wall seconds, its compile seconds and
-    which programs it compiled or loaded from the persistent cache."""
+    """Run one phase; print its wall seconds, its compile seconds, which
+    programs it compiled or loaded from the persistent cache, and the
+    path each attention call it traced took (``AttentionPaths``)."""
     before = clock.snapshot()
     t0 = time.perf_counter()
-    out = fn(**kwargs)
+    with AttentionPaths() as paths:
+        out = fn(**kwargs)
     wall = time.perf_counter() - t0
     seconds, compiled, loaded = clock.since(before)
 
@@ -86,7 +90,8 @@ def run_phase(clock, name: str, fn, **kwargs):
                          for n, c in sorted(counts.items())) or "none"
     print(f"{name}: passed in {wall:.2f} s wall; compile {seconds:.2f} s; "
           f"compiled: {names(compiled)}; from the persistent cache: "
-          f"{names(loaded)}", flush=True)
+          f"{names(loaded)}; attention paths traced: {names(paths)}",
+          flush=True)
     return out
 
 

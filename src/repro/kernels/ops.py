@@ -12,6 +12,7 @@ import functools
 
 import jax
 
+from repro.kernels import causal_attention as _ca
 from repro.kernels import flash_attention as _fa
 from repro.kernels import moe_gmm as _gmm
 from repro.kernels import rglru as _rglru
@@ -33,6 +34,14 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0):
     """q [B,Hq,S,dh], k/v [B,Hkv,S,dh] -> [B,Hq,S,dh]."""
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                interpret=_interpret())
+
+
+@jax.jit
+def causal_attention(q, k, v):
+    """q [B,S,Hq,dh], k/v [B,S,Hkv,dh] -> [B,S,Hq,dh]: blocked causal
+    self-attention, forward and backward kernels; S and dh multiples of
+    128; positions must be ``arange(S)``."""
+    return _ca.causal_attention(q, k, v, interpret=_interpret())
 
 
 @jax.jit

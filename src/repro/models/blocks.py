@@ -142,7 +142,11 @@ def _ffn_decode(p, x, cfg: LMConfig, dt, per_lane: bool):
 
 def apply_block(p, x, cfg: LMConfig, kind: str, *, positions,
                 prefix_len: int = 0, enc_out=None, use_rope: bool = True):
-    """x: [B, S, D] -> ([B, S, D], aux_loss)."""
+    """x: [B, S, D] -> ([B, S, D], aux_loss).
+
+    ``positions`` must be ``arange(S)``: causal self-attention takes the
+    blocked kernel where ``chunked_attention`` allows it, and the kernel
+    masks by index, not by these positions."""
     dt = x.dtype
     if kind == "rwkv":
         h = apply_norm(x, p["ln1"], cfg.norm)
@@ -223,7 +227,9 @@ def _kv_into_cache(k, v, cache_len: int, window: int = 0):
 def apply_block_prefill(p, x, cfg: LMConfig, kind: str, *, positions,
                         cache_len: int, prefix_len: int = 0, enc_out=None,
                         use_rope: bool = True):
-    """Full-sequence forward returning (y, aux, decode_state)."""
+    """Full-sequence forward returning (y, aux, decode_state).
+
+    ``positions`` must be ``arange(S)``, as in ``apply_block``."""
     dt = x.dtype
     if kind == "rwkv":
         h = apply_norm(x, p["ln1"], cfg.norm)

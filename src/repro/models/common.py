@@ -1,10 +1,13 @@
 """Shared model building blocks (pure JAX, functional, pytree params)."""
 from __future__ import annotations
 
+import collections
 import math
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels import causal_attention as _ca
 
 
 def dense_init(key, d_in: int, d_out: int, dtype=jnp.float32):
@@ -105,17 +108,86 @@ def _attend_block(q, k, v, mask):
     return out.reshape(b, hq, g, dh).astype(q.dtype)
 
 
+class AttentionPaths(collections.Counter):
+    """The path of each attention call traced while this counter is
+    entered (``with paths:``): ``"kernel"``, or ``"xla: <reason>"`` for
+    the jnp path and why the kernel did not take the call.  A traced
+    call is one per program and shape: a scanned layer stack traces its
+    layers' attention once.  Counters nest; each entered one counts."""
+
+    def __enter__(self):
+        _RECORDING.append(self)
+        return self
+
+    def __exit__(self, *exc):
+        # by identity: a Counter equals any other of the same counts
+        del _RECORDING[max(i for i, c in enumerate(_RECORDING)
+                           if c is self)]
+
+
+_RECORDING: list = []
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def attention_path(q, k, *, self_attention: bool, causal: bool,
+                   window: int = 0, prefix_len: int = 0) -> str:
+    """Which path an attention call takes: ``"kernel"`` (the blocked
+    Pallas kernel, ``kernels/causal_attention.py``) or ``"xla: <reason>"``.
+
+    The kernel takes causal self-attention over one sequence, with no
+    window and no prefix, S and dh multiples of 128, on a TPU backend.
+    """
+    s, dh = q.shape[1], q.shape[-1]
+    if not _on_tpu():
+        why = f"backend {jax.default_backend()}"
+    elif not self_attention or k.shape[1] != s:
+        why = "cross"
+    elif not causal:
+        why = "not causal"
+    elif window > 0:
+        why = "window"
+    elif prefix_len > 0:
+        why = "prefix"
+    elif s % _ca.BLOCK:
+        why = f"seq {s} % {_ca.BLOCK}"
+    elif dh % _ca.BLOCK:
+        why = f"head_dim {dh} % {_ca.BLOCK}"
+    else:
+        return "kernel"
+    return f"xla: {why}"
+
+
 def chunked_attention(q, k, v, *, q_positions, kv_positions, causal: bool,
                       window: int = 0, prefix_len: int = 0,
                       q_chunk: int = 512):
     """Memory-efficient attention.
 
     q: [B, Sq, Hq, dh]; k, v: [B, Skv, Hkv, dh].
-    Never materializes [B, H, Sq, Skv]; peak scratch is [B, H, q_chunk, Skv].
     ``window`` > 0 restricts to a sliding causal window (local attention).
     ``prefix_len`` > 0 makes positions < prefix_len bidirectional (VLM
     prefix-LM masking).
+
+    Self-attention passes one positions array as both ``q_positions``
+    and ``kv_positions``, and it must be ``arange(S)``: where
+    ``attention_path`` takes the kernel, it masks by index and never
+    reads the positions.  Every other call (and each off the TPU) takes
+    the jnp path: a scan over query chunks that
+    never materializes [B, H, Sq, Skv]; its peak scratch is
+    [B, H, q_chunk, Skv].  Each traced call is counted in the entered
+    ``AttentionPaths``.
     """
+    path = attention_path(q, k, self_attention=kv_positions is q_positions,
+                          causal=causal, window=window,
+                          prefix_len=prefix_len)
+    for paths in _RECORDING:
+        paths[path] += 1
+    if path == "kernel":
+        from repro.kernels import ops
+        return ops.causal_attention(q, k, v)
+
     b, sq, hq, dh = q.shape
     skv = k.shape[1]
     qt = jnp.swapaxes(q, 1, 2)          # [B,Hq,Sq,dh]

@@ -13,6 +13,9 @@ occupy which slots:
   admission: a decode step writes one token's K and V per lane per layer
   into it in place, and nothing else of it (``arena_inplace`` counts the
   programs that consumed the arena they were given);
+* ``attention_paths`` counts the path each attention call the engine's
+  programs traced took (``models/common.py``): prefill's causal
+  self-attention takes the blocked kernel on a TPU, decode never;
 * temperature sampling runs INSIDE the jit with per-request keys
   (``fold_in(fold_in(key(seed), rid), token_index)``) — reproducible
   and independent of slot assignment and batch composition;
@@ -43,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from repro.models.common import AttentionPaths
 from repro.runtime.qos import ServingQoS
 from repro.serving import kv
 from repro.serving.scheduler import Request, Scheduler
@@ -142,6 +146,7 @@ class ServingEngine:
         self.lane_steps = 0                       # active lanes, summed
 
         self.arena_inplace = 0                    # arena programs in place
+        self.attention_paths = AttentionPaths()   # traced calls, by path
 
         # the arena (argument 1 of the step, 0 of the row write) is
         # donated: each program writes into it and returns it
@@ -220,7 +225,8 @@ class ServingEngine:
     def _admit_chunk(self, chunk: list) -> None:
         prompts = jnp.asarray(np.stack([r.prompt for r in chunk]),
                               jnp.int32)
-        bucket_cache, tok0 = self._prefill(self.params, prompts)
+        with self.attention_paths:
+            bucket_cache, tok0 = self._prefill(self.params, prompts)
         tok0 = np.asarray(tok0)
         self.prefill_chunks += 1
         self.engine_units += int(prompts.size)
@@ -323,6 +329,7 @@ class ServingEngine:
             "engine_units": self.engine_units,
             "lane_steps": self.lane_steps,
             "arena_inplace": self.arena_inplace,
+            "attention_paths": dict(self.attention_paths),
             "occupancy_mean": (self.lane_steps / self.decode_steps
                                if self.decode_steps else 0.0),
             "qos": self.qos.snapshot(),

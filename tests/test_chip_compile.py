@@ -1,6 +1,7 @@
-"""The fused wire codec compiled for a TPU v5e that is described, not
-attached: Mosaic's checks (block tiling, VMEM) run here at the real hop
-widths, where interpret mode accepts every shape.
+"""The fused wire codec and the blocked attention kernel compiled for a
+TPU v5e that is described, not attached: Mosaic's checks (block tiling,
+VMEM) run here at the real widths, where interpret mode accepts every
+shape.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and every test worker
@@ -8,6 +9,7 @@ imports this file.  The fixture skips where no v5e can be described.
 """
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -94,3 +96,81 @@ def test_codec_kernels_keep_their_names_for_the_benchmark(one_chip,
         assert all(match(c) for c in calls), calls
         assert not any(roofline.is_encode(c) and roofline.is_decode(c)
                        for c in calls)
+
+
+#: qwen1.5-4b's attention on one micro-batch of the training cells:
+#: 20 heads of 128 over 4,096 tokens, and a GQA grouping of the same
+ATTN_SHAPES = [pytest.param((1, 4096, 20, 128), 20, id="mha-4096x20x128"),
+               pytest.param((1, 4096, 20, 128), 4, id="gqa-4096x20:4x128")]
+
+
+@pytest.mark.parametrize("q_shape,n_kv", ATTN_SHAPES)
+def test_causal_attention_compiles_for_v5e(one_chip, q_shape, n_kv):
+    """The blocked attention kernel, forward and both backward kernels,
+    at the real width: Mosaic's tiling and VMEM checks."""
+    from repro.kernels.causal_attention import causal_attention
+
+    b, s, _, dh = q_shape
+    q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, s, n_kv, dh), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(causal_attention(q, k, v).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    kernels = {n.split("_no_residuals")[0].split("_residuals")[0]
+               for n in re.findall(r"(splash_\w+)", text)}
+    assert {"splash_mha_fwd", "splash_mha_dq", "splash_mha_dkv"} <= kernels \
+        or {"splash_mqa_fwd", "splash_mqa_dq", "splash_mqa_dkv"} <= kernels, \
+        kernels
+
+
+def test_attention_kernel_calls_carry_the_attention_scope(one_chip,
+                                                          monkeypatch):
+    """In a train step compiled for the chip with the kernel path, every
+    custom call of the attention kernel (forward, recomputed forward,
+    dQ and dKV) carries the ``attention`` scope, as the benchmark's
+    scope table reads it: ``attention_ms`` keeps counting its time."""
+    from repro.kernels import ops
+    from repro.models import LM, LMConfig, common
+    from repro.parallel.steps import make_lm_train_step
+    from repro.training.optim import adamw
+
+    bench = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                         "bench")
+    monkeypatch.syspath_prepend(bench)
+    from harness import scopes
+
+    monkeypatch.setattr(common, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = LMConfig(name="t", num_layers=2, d_model=256, n_heads=2, n_kv=2,
+                   d_ff=512, vocab=256, qkv_bias=True, dtype="bfloat16")
+    model, opt = LM(cfg), adamw(1e-3)
+    state = jax.eval_shape(lambda: (lambda p: {
+        "params": p, "opt_state": opt.init(p),
+        "step": jnp.zeros((), jnp.int32)})(model.init(jax.random.key(0))))
+    state = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip), state)
+    tok = jax.ShapeDtypeStruct((4, 512), jnp.int32, sharding=one_chip)
+    with common.AttentionPaths() as paths:
+        text = jax.jit(make_lm_train_step(model, opt, microbatches=2)).lower(
+            state, {"tokens": tok, "labels": tok}).compile().as_text()
+    assert set(paths) == {"kernel"}
+    table = scopes.scope_table(text)
+    calls = [line.strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "splash_" in line]
+    names = {re.search(r"(splash_\w+)", c).group(1) for c in calls}
+    assert any("fwd" in n for n in names) and any("dq" in n for n in names) \
+        and any("dkv" in n for n in names), names
+    for call in calls:
+        instr = trace_name(call)
+        assert table[instr] == "attention", (instr, table[instr])
+
+
+def trace_name(line: str) -> str:
+    """The instruction name of an HLO line, as a trace names the op."""
+    from harness import trace
+    return trace.short_name(line).split(" ", 1)[0]
